@@ -35,6 +35,7 @@ import time
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
+import jax
 import numpy as np
 
 try:
@@ -249,8 +250,8 @@ def run(verbose: bool = True, smoke: bool = False) -> dict:
 
         sat = sweep["saturation"]
         payload = {
-            "backend": "cpu-interpret",
-            "dispatch_path": "reference (vmapped oracle through executor)",
+            "backend": jax.default_backend(),
+            "dispatch_path": "kernel through executor",
             "max_batch": MAX_BATCH,
             "flush_deadline_ms": 50.0,
             "service_time_ms_per_bucket": {

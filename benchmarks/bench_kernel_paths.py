@@ -114,8 +114,10 @@ def run(verbose: bool = True, n_rows: int = 8192, n_cols: int = 256,
                           f"{t*1e3:8.2f} ms  {nnz/t/1e9:.4f} GNNZ/s")
 
     # --- sweep 2: stage-1 gather flavors on both layouts (F32, linear) ---
-    auto_mode = ops.default_gather_mode()
-    for gather in ("take", "onehot"):
+    auto_mode = ops.resolve_gather_mode("auto")
+    interpret = ops.default_interpret()
+    # "take" is the interpret-only reference gather; compiled kernels refuse it
+    for gather in ("take", "onehot") if interpret else ("onehot",):
         ts = time_paired({
             layout: (lambda g=gather, l=layout: ops.topk_spmv_blocked(
                 x, packed[l], BIG_K, k=K, packets_per_step=T_STEP,
@@ -382,7 +384,7 @@ def run(verbose: bool = True, n_rows: int = 8192, n_cols: int = 256,
     payload = {
         "bench": "bench_kernel_paths",
         "backend": jax.default_backend(),
-        "interpret": True,
+        "interpret": interpret,
         "matrix": {"n_rows": n_rows, "n_cols": n_cols, "nnz": nnz,
                    "distribution": "gamma"},
         "design_point": {"block_size": block, "packets_per_step": T_STEP,

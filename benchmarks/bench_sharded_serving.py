@@ -28,7 +28,9 @@ Results merge into ``BENCH_topk_spmv.json`` under ``sharded_serving``.
 ``--smoke`` (CI) runs tiny shapes through the same assertions, no json.
 
 The measurement runs in a child process so the forced device count never
-leaks into (or is blocked by) the parent's already-initialized jax.
+leaks into (or is blocked by) the parent's already-initialized jax.  The
+child is pinned to the CPU backend (``JAX_PLATFORMS=cpu``): its devices are
+simulated host devices, and on a TPU host the parent already holds the chip.
 """
 from __future__ import annotations
 
@@ -198,6 +200,7 @@ def _child_main(smoke: bool) -> None:
 
 def run(verbose: bool = True, smoke: bool = False) -> dict:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"  # simulated host devices; never the chip
     env["PYTHONPATH"] = os.pathsep.join(
         [str(_REPO_ROOT / "src"), env.get("PYTHONPATH", "")]
     ).rstrip(os.pathsep)

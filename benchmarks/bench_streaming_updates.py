@@ -275,7 +275,7 @@ def run(verbose: bool = True, n_rows: int = 4096, n_cols: int = 256,
 
     payload = {
         "backend": jax.default_backend(),
-        "interpret": True,
+        "interpret": jax.default_backend() != "tpu",
         "matrix": {"n_rows": n_rows, "n_cols": n_cols, "nnz": csr.nnz,
                    "distribution": "gamma"},
         "design_point": {"block_size": BLOCK, "packets_per_step": T_STEP,

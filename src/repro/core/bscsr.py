@@ -43,9 +43,11 @@ per grid step and recovers the fields with shift/mask bit-ops::
                +-----------------+------------------------+-----------------+
   Wf = B/32 words        Wc = B*col_bytes/4 words   Wv = B*val_bytes/4 words
 
-All sub-fields are little-endian within a word (value ``2i`` in the low half,
-``2i+1`` in the high half; int8 packs 4/word), so host-side fusing is a plain
-``.view(int32)`` + concatenate and the in-kernel decode is shifts and masks.
+Narrow sub-fields are *planar* within their section: with ``n`` values per
+word (2 for int16/bf16, 4 for int8), sub-word ``q`` of word ``i`` holds entry
+``q * B/n + i``.  Each sub-word plane is then a contiguous run of entries, so
+the in-kernel decode is shifts and masks plus a lane concatenation of the
+planes — no lane interleave, which the TPU compiler does not lower.
 Fused and split forms are bit-identical in content and total bytes; the win
 is stream *count* (3 -> 1 contiguous burst per core per step).
 
@@ -317,27 +319,40 @@ def fused_word_counts(
     )
 
 
+def _planar_words(a: np.ndarray) -> np.ndarray:
+    """(..., B) values -> (..., B*itemsize/4) int32 words, planar sub-words."""
+    n = 4 // a.dtype.itemsize
+    b = a.shape[-1]
+    planes = a.reshape(*a.shape[:-1], n, b // n).swapaxes(-1, -2)
+    return np.ascontiguousarray(planes).reshape(a.shape).view(np.int32)
+
+
+def _from_planar_words(words: np.ndarray, dtype) -> np.ndarray:
+    """Inverse of :func:`_planar_words`."""
+    a = np.ascontiguousarray(words).view(np.dtype(dtype))
+    n = a.shape[-1] // words.shape[-1]
+    planes = a.reshape(*words.shape, n).swapaxes(-1, -2)
+    return np.ascontiguousarray(planes).reshape(a.shape)
+
+
 def fuse_words(
     vals: np.ndarray, cols: np.ndarray, flags: np.ndarray, tag: Optional[int] = None
 ) -> np.ndarray:
     """Pack split ``(..., B)``/``(..., B//32)`` arrays into fused int32 words.
 
     The single definition of the fused word layout (``flags | cols | vals``
-    per packet row, little-endian sub-words): every byte lands unchanged via
-    ``view(int32)``, so ``defuse_stream`` round-trips losslessly and the
-    in-kernel decode (`kernels/bscsr_topk_spmv._decode_fused_tile`)
+    per packet row, planar sub-words — see the module docstring): every
+    byte lands unchanged, so ``defuse_stream`` round-trips losslessly and the
+    in-kernel decode (`kernels/bscsr_topk_spmv._decode_fused`)
     reconstructs bit-identical operands.
 
     ``tag`` (mixed-precision snapshots only) prepends one header word per
     packet row carrying the partition's value-format code — see the tagged
     diagram in the module docstring.  ``None`` keeps the homogeneous layout.
     """
-    flag_w = np.ascontiguousarray(flags)
-    col_w = np.ascontiguousarray(cols).view(np.int32)
-    val_w = np.ascontiguousarray(vals).view(np.int32)
-    parts = [flag_w, col_w, val_w]
+    parts = [np.ascontiguousarray(flags), _planar_words(cols), _planar_words(vals)]
     if tag is not None:
-        header = np.full(flag_w.shape[:-1] + (1,), int(tag), dtype=np.int32)
+        header = np.full(flags.shape[:-1] + (1,), int(tag), dtype=np.int32)
         parts.insert(0, header)
     return np.concatenate(parts, axis=-1)
 
@@ -378,8 +393,8 @@ def defuse_stream(
             )
         words = words[..., 1:]
     flags = np.ascontiguousarray(words[..., :wf])
-    cols = np.ascontiguousarray(words[..., wf : wf + wc]).view(np.dtype(col_dtype))
-    vals = np.ascontiguousarray(words[..., wf + wc :]).view(fmt.np_dtype)
+    cols = _from_planar_words(words[..., wf : wf + wc], col_dtype)
+    vals = _from_planar_words(words[..., wf + wc :], fmt.np_dtype)
     return vals, cols, flags
 
 

@@ -83,8 +83,6 @@ from repro.core import partition as partition_lib
 # attribute to the function of the same name, so the module object is not
 # reachable as ``repro.core.topk_spmv`` once the package is initialised.
 from repro.core.topk_spmv import (
-    _SHARD_MAP_KW,
-    _shard_map,
     MutableTopKSpMVIndex,
     TopKSpMVConfig,
     expected_precision,
@@ -937,9 +935,9 @@ class _SpmdDispatcher:
             + (shard_spec,) * (len(args) - 1) + (rep,)
         )
         out_specs = rep
-        fn = _shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            **_SHARD_MAP_KW,
+            check_vma=False,  # pallas_call outputs carry no vma info
         )
         return jax.jit(
             fn,
@@ -966,12 +964,11 @@ class _SpmdDispatcher:
             k=k, n_rows=max_slots,
             packets_per_step=cfg.packets_per_step,
             fmt_name=pack0.value_format.name,
+            gather_mode=self._gather,
             inner_loop=cfg.inner_loop,
             stream_layout=layout, block_size=pack0.block_size,
             interpret=self._interpret,
         )
-        if q is None:
-            kwargs["gather_mode"] = self._gather
 
         def merge_pair(v1, r1, v2, r2, gsent):
             def m(a, b, c, d):
@@ -1037,9 +1034,9 @@ class _SpmdDispatcher:
             (xspec,) + (shard_spec,) * (len(args) - 1) + (PartitionSpec(),)
         )
         out_specs = (xspec, xspec)
-        fn = _shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            **_SHARD_MAP_KW,
+            check_vma=False,  # pallas_call outputs carry no vma info
         )
         return jax.jit(
             fn,
@@ -1134,5 +1131,7 @@ class _SpmdDispatcher:
             "dispatches": self.dispatches,
             "q_bucket_hits": self.q_bucket_hits,
             "q_exact_hits": self.q_exact_hits,
+            "interpret": self._interpret,
+            "gather_mode": self._gather,
             "bundle": self.bundle.counters(),
         }

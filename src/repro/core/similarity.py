@@ -187,21 +187,16 @@ class SparseEmbeddingIndex:
         return v[0], r[0]
 
     def query_batch(
-        self, xs: np.ndarray, use_kernel: bool = False
+        self, xs: np.ndarray, use_kernel: bool = True
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Batched queries.
 
-        With ``use_kernel`` the multi-query Pallas kernel answers all Q
-        queries in ONE pass over the stream (per-query bytes/nnz divided by
-        Q — the beyond-paper optimization, EXPERIMENTS.md §Perf C4).
-
-        The default deliberately differs from ``query(use_kernel=True)``:
-        off-TPU the kernel runs under Pallas ``interpret`` mode, whose
-        per-packet Python dispatch is tolerable for one query but multiplies
-        across a batch, while the vmapped jnp oracle compiles to one XLA
-        program that evaluates the *identical* partitioned approximation.
-        On real TPU silicon pass ``use_kernel=True`` to get the one-pass
-        stream amortization the kernel exists for.
+        By default the multi-query Pallas kernel answers all Q queries in
+        ONE pass over the stream (per-query bytes/nnz divided by Q — the
+        beyond-paper optimization, EXPERIMENTS.md §Perf C4).  The kernel is
+        compiled on a TPU and interpreted on the CPU (``config.interpret``).
+        ``use_kernel=False`` asks for the vmapped jnp reference oracle by
+        name: it evaluates the identical partitioned approximation.
         """
         self._validate_query(xs, batched=True)
         return self._dispatch_batch(xs, use_kernel=use_kernel)

@@ -29,17 +29,6 @@ from repro.kernels import executor as executor_lib
 from repro.kernels import ops as kernel_ops
 from repro.kernels import ref as ref_lib
 
-# shard_map moved to the jax namespace (and check_rep became check_vma) in
-# newer releases; support both so the distributed path runs on either.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}  # pallas_call outputs carry no vma info
-else:  # pragma: no cover - exercised on older jax only
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
-
-
 @dataclasses.dataclass(frozen=True)
 class TopKSpMVConfig:
     """User-facing knobs; mirrors the paper's design space (Table II)."""
@@ -58,7 +47,8 @@ class TopKSpMVConfig:
     calibration_queries: int = 16  # query sample size for the autotuner
     calibration_seed: int = 0      # deterministic per (seed, collection)
     packets_per_step: int = 2      # T
-    gather_mode: str = "auto"      # take | onehot | auto (per-backend microbench)
+    gather_mode: str = "auto"      # onehot (= auto, the compiled MXU gather)
+                                   # | take (interpret-only reference gather)
     inner_loop: str = "linear"     # linear | legacy (+ mixed, for parity tests)
     stream_layout: str = "fused"   # fused (one burst/step) | split (legacy 3-array)
     incremental_snapshots: bool = True  # mutable index: re-pad only mutated parts
@@ -77,7 +67,8 @@ class TopKSpMVConfig:
                                    # compiled signature per bucket — zero
                                    # retraces between bucket doublings.
                                    # False: exact dims (retrace per refresh).
-    interpret: Optional[bool] = None  # None -> interpret unless on real TPU
+    interpret: Optional[bool] = None  # None -> compiled on a TPU, interpreted
+                                      # on the CPU, refused elsewhere
 
     def resolve_partitions(self, n_rows: int) -> int:
         if self.num_partitions is not None:
@@ -88,9 +79,7 @@ class TopKSpMVConfig:
         return max(c, -(-self.big_k // self.k))
 
     def resolve_interpret(self) -> bool:
-        if self.interpret is not None:
-            return self.interpret
-        return jax.default_backend() != "tpu"
+        return kernel_ops.resolve_interpret(self.interpret)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1122,6 +1111,7 @@ def topk_spmv_batched(
             big_k=cfg.big_k,
             k=cfg.k,
             packets_per_step=cfg.packets_per_step,
+            gather_mode=cfg.gather_mode,
             inner_loop=cfg.inner_loop,
             interpret=cfg.resolve_interpret(),
         )
@@ -1186,17 +1176,17 @@ def distributed_topk_spmv_fn(
         jax.device_put(jnp.asarray(a), core_sharded) for a in host_arrays
     )
     n_streams = len(device_arrays)
-    row_starts = jax.device_put(jnp.asarray(packed.row_starts), core_sharded)
-    rows_per = jax.device_put(jnp.asarray(packed.candidate_slots), core_sharded)
+    # The merge sees all c*k candidates, so its per-core inputs are replicated.
+    row_starts = jax.device_put(jnp.asarray(packed.row_starts), replicated)
+    rows_per = jax.device_put(jnp.asarray(packed.candidate_slots), replicated)
     slot_to_row = None
     if packed.slot_to_row is not None:
-        slot_to_row = jax.device_put(jnp.asarray(packed.slot_to_row), core_sharded)
+        slot_to_row = jax.device_put(jnp.asarray(packed.slot_to_row), replicated)
     tombstones = None
     if packed.has_tombstones:  # computed once at snapshot build
         tombstones = jax.device_put(jnp.asarray(packed.tombstones), replicated)
     max_rows = packed.max_slots
     interpret = cfg.resolve_interpret()
-    # Resolve "auto" eagerly: the microbenchmark must not run under tracing.
     gather_mode = kernel_ops.resolve_gather_mode(cfg.gather_mode)
 
     def _local(x, *streams):
@@ -1206,8 +1196,7 @@ def distributed_topk_spmv_fn(
         )
 
         kernel = bscsr_topk_spmv_multiquery if batched else bscsr_topk_spmv
-        kwargs = {} if batched else {"gather_mode": gather_mode}
-        return kernel(
+        lv, lr = kernel(
             x,
             *streams,
             k=cfg.k,
@@ -1217,9 +1206,12 @@ def distributed_topk_spmv_fn(
             inner_loop=cfg.inner_loop,
             stream_layout=layout,
             block_size=packed.block_size,
+            gather_mode=gather_mode,
             interpret=interpret,
-            **kwargs,
         )
+        # c*k candidates: tiny; one all-gather hands every device the merge.
+        return (jax.lax.all_gather(lv, shard_axis, tiled=True),
+                jax.lax.all_gather(lr, shard_axis, tiled=True))
 
     @partial(
         jax.jit,
@@ -1227,14 +1219,13 @@ def distributed_topk_spmv_fn(
         out_shardings=(replicated, replicated),
     )
     def query(x, *streams):
-        lv, lr = _shard_map(
+        lv, lr = jax.shard_map(
             _local,
             mesh=mesh,
             in_specs=(P(),) + (P(shard_axis),) * n_streams,
-            out_specs=(P(shard_axis), P(shard_axis)),
-            **_SHARD_MAP_KW,
+            out_specs=(P(), P()),
+            check_vma=False,  # pallas_call outputs carry no vma info
         )(x, *streams)
-        # c*k candidates: tiny; XLA inserts one small all-gather for the merge.
         finalize = (
             kernel_ops.finalize_candidates_batched
             if batched

@@ -1,61 +1,61 @@
-"""Pallas TPU kernel for multi-core BS-CSR Top-K SpMV (paper §IV, Alg. 1).
+"""Pallas TPU kernels for multi-core BS-CSR Top-K SpMV (paper §IV, Alg. 1).
 
 Grid = (cores, steps): grid dim 0 is the paper's "core" (one row-partition per
 core, iterated major), dim 1 streams that core's tile-packets in order — the
 TPU analogue of one HBM channel feeding one core in max-length bursts.  All
-per-core state lives in on-chip scratch, exactly mirroring the FPGA design:
+per-core state lives in on-chip scratch, exactly mirroring the FPGA design.
 
-  stage 1  load packet tile, gather x from VMEM (URAM analogue), multiply
-  stage 2  row-aggregate within the tile: O(TB) cumsum-difference reduction —
-           inclusive prefix sum of the products, scattered at the
-           segment-end (row-boundary) positions and first-differenced, so
-           each segment sum is the difference of two prefix values.  The FPGA
-           used an unrolled adder chain over the packet; this is its
-           constant-work-per-element TPU analogue.
-  stage 3  cross-packet carry bookkeeping (current row id + partial sum in
-           SMEM — the paper's ``new_row`` / ``last_packet_output``)
-  stage 4  top-k scratchpad update via threshold-filter-then-merge (paper
-           §IV-B): candidates are first filtered against the running k-th
-           value ``min(acc_v)`` — the paper's scratchpad admission test —
-           then the <=k survivors from one vectorized ``lax.top_k`` are
-           merged with the scratchpad in a single 2k-wide top-k.  Work per
-           packet is O(TB + k log k), not O(k·TB).
+Every stage is written in ops the Mosaic compiler lowers (no 1-D gather,
+cumsum, scatter, ``top_k`` or dynamic vector indexing), so the SAME body
+compiles for the chip and runs under the Pallas interpreter in the CPU tests.
+One grid step holds ``E = T*B`` stream entries as a ``(1, E)`` lane row in
+natural stream order:
 
-The legacy quadratic inner loops (stage 2 as a (TB, TB+1) one-hot matmul on
-the MXU, stage 4 as k serial argmax-extract sweeps over the whole pool) are
-kept behind ``inner_loop`` for parity testing and as a fallback where the
-Mosaic lowering of scatter/top_k is unavailable:
+  stage 1  decode the packet tile (shift/mask, see ``bscsr.fuse_words``), then
+           gather x with a one-hot matmul on the MXU at ``precision=HIGHEST``
+           (``gather_mode="onehot"``; ``"take"`` is an interpret-only
+           reference gather) and multiply.
+  stage 2  segment ids are the prefix count of the row-start flags, computed
+           as a matmul with a triangular 0/1 matrix; per-segment sums are a
+           matmul with the one-hot (segment x entry) matrix.
+  stage 3  cross-step carry (current row id in SMEM, per-query partial sum —
+           the paper's ``new_row`` / ``last_packet_output``), read with masked
+           reductions.
+  stage 4  top-k scratchpad update: k passes of ``max`` + first-``argmax``
+           over the scratchpad and the step's candidates, using iota masks.
 
-  inner_loop = "linear"       cumsum-difference + threshold-merge (default)
-               "legacy"       one-hot matmul   + k-pass argmax
-               "linear-seg"   cumsum-difference + k-pass argmax
-               "linear-topk"  one-hot matmul   + threshold-merge
+``inner_loop`` selects the stage-2 and stage-4 variants:
 
-Both tie-break identically (stable ``argmax`` / stable ``top_k``: scratchpad
-entries beat equal-valued candidates, lower row ids beat higher), so
-"linear-topk" is bit-identical to "legacy"; the cumsum-difference reduction
-changes only the float summation order.
+  inner_loop = "linear"       prefix-difference sums + gated k-pass (default)
+               "legacy"       one-hot sums            + k-pass every step
+               "linear-seg"   prefix-difference sums + k-pass every step
+               "linear-topk"  one-hot sums            + gated k-pass
+
+The prefix-difference sum takes the inclusive prefix sum of the products
+(triangular matmul), picks it at each segment's last entry (one-hot matmul)
+and first-differences it; the one-hot sum adds each segment directly.  They
+differ only in float summation order.  The gated k-pass skips the update when
+no candidate beats the running k-th value (the paper's scratchpad admission
+test, §IV-B); the scratchpad stays sorted, so gated and ungated results are
+bit-identical.  Ties go to the scratchpad entry, then to the lower row id.
 
 The kernel never writes row scores to HBM: per core only k (value, row) pairs
 leave the chip, which is the paper's key bandwidth argument (§III-A).
 
 Stream layouts (``stream_layout``):
 
-  "split"   vals / cols / flags as three BlockSpec streams per grid step —
-            the original three-array pipeline, kept as the parity fallback.
+  "split"   vals / cols / flags as three BlockSpec streams per grid step.
   "fused"   one contiguous int32 word stream per core (``bscsr.fuse_stream``:
             ``flags | cols | vals`` per packet — the TPU analogue of the
-            paper's single 512-bit HBM transaction).  Every grid step then
-            pipelines exactly ONE VMEM block from ONE contiguous HBM region;
-            cols (int16 pairs) and vals (bf16/int16 pairs, int8 quads, or f32
-            bitcast) are recovered in-kernel with shift/mask bit-ops.  The
-            decode is bit-exact, so fused results are bit-identical to split
-            on every inner_loop mode.
+            paper's single 512-bit HBM transaction), decoded bit-exactly, so
+            fused results are bit-identical to split.
 
-Stage-1 gather hardening: padded/sentinel stream entries carry whatever col
-id the encoder (or a corrupted segment) left behind, so the x-gather uses
-explicit clip+mask semantics — out-of-range ids read x[clip] and are zeroed —
-instead of relying on backend-specific out-of-bounds behavior.
+Streams enter as ``(C, steps, T, W)`` with the core and step block dims
+squeezed, so every block's trailing two dims equal the array's; outputs are
+``(C, Q, k)`` (top-k) or ``(C, 1, n_rows)`` (accumulate).
+
+Out-of-range col ids (padding/sentinel entries carry whatever the encoder or
+a corrupted segment left) gather 0: the one-hot row is all zero.
 
 Scratch-shape analysis for padded (bucketed) slot counts
 --------------------------------------------------------
@@ -67,20 +67,16 @@ compiled signature.  Padding a *slot count* is hazardous in general: a slot
 that exists only as padding has no non-zeros, so any naive materialization
 scores it 0.0, and a zero-score phantom admitted to the k-sized stage-4
 scratchpad displaces a real candidate whenever the true top-k scores are
-negative — silently changing answers in a way no positive-score test
-catches.  The padding is safe here because phantom slots are only ever
+negative.  The padding is safe here because phantom slots are only ever
 materialized at NEG_INF:
 
-  * in-kernel, candidate slots exist ONLY where the stream carries row-start
-    flags (stage 2/3 derive them from ``cumsum(flags)``), and flag-free
-    padding packets merely extend the open trailing sentinel row, which
-    stage 3 never completes — so bucketing ``n_rows`` or the packet count
-    adds NO candidates.  The only scratchpad entries a padded slot id ever
-    occupies are the stage-4 ``acc_v/acc_r`` init sentinels, and those are
-    materialized at NEG_INF/``n_rows`` — below every real candidate,
-    including arbitrarily negative ones (the threshold filter admits on
-    strict ``>``, so a NEG_INF sentinel never beats a NEG_INF-filtered
-    candidate either);
+  * in-kernel, a candidate is complete ONLY where the stream carries a
+    row-start flag after it, and flag-free padding packets merely extend the
+    open trailing sentinel row, which stage 3 never completes — so bucketing
+    ``n_rows`` or the packet count adds NO candidates.  The only scratchpad
+    entries a padded slot id ever occupies are the stage-4 init sentinels at
+    NEG_INF/``n_rows``, below every real candidate (admission is a strict
+    ``>``, so a sentinel never beats a NEG_INF-masked candidate either);
   * the jnp reference oracle (``ref.bscsr_topk_ref_stacked``) DOES
     materialize one score per budgeted slot, so it masks slots >= the
     per-core live count to NEG_INF *before* its local top-k;
@@ -111,12 +107,16 @@ from repro.core.quantization import (
 
 NEG_INF = float(np.finfo(np.float32).min)
 FLAG_WORD_BITS = 32
+LANES = 128
 
 INNER_LOOPS = ("linear", "legacy", "linear-seg", "linear-topk")
+GATHER_MODES = ("onehot", "take")
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _inner_loop_flags(inner_loop: str) -> Tuple[bool, bool]:
-    """-> (linear stage-2 segmented sum?, linear stage-4 scratchpad update?)."""
+    """-> (prefix-difference stage-2 sums?, gated stage-4 update?)."""
     if inner_loop not in INNER_LOOPS:
         raise ValueError(f"inner_loop must be one of {INNER_LOOPS}, got {inner_loop!r}")
     return (
@@ -125,260 +125,315 @@ def _inner_loop_flags(inner_loop: str) -> Tuple[bool, bool]:
     )
 
 
-def _unpack_flags_tile(words: jnp.ndarray, tb: int) -> jnp.ndarray:
-    """(T*B/32,) int32 words -> (T*B,) int32 {0,1} row-start bits."""
-    w = words.reshape(-1).astype(jnp.uint32)
-    shifts = jnp.arange(FLAG_WORD_BITS, dtype=jnp.uint32)
-    bits = (w[:, None] >> shifts[None, :]) & jnp.uint32(1)
-    return bits.reshape(tb).astype(jnp.int32)
+def _check_gather_mode(gather_mode: str, interpret: bool) -> None:
+    if gather_mode not in GATHER_MODES:
+        raise ValueError(f"gather_mode must be one of {GATHER_MODES}, got {gather_mode!r}")
+    if gather_mode == "take" and not interpret:
+        raise ValueError(
+            "gather_mode='take' is an interpret-only reference gather (Mosaic "
+            "lowers no 1-D gather); compiled kernels gather with 'onehot'"
+        )
 
 
-def _decode_val_words(vw, fmt: ValueFormat, tb: int):
-    """One value section's int32 words -> (tb,) f32, per storage dtype."""
+def _iota(shape, dim: int) -> jnp.ndarray:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _dot_nt(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """a (Q, E) . b (S, E)^T -> (Q, S), f32 at full precision."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())),
+        precision=_HIGHEST, preferred_element_type=jnp.float32,
+    )
+
+
+# --------------------------------------------------------------------------
+# Stage-1 decode: packet tiles (T, ...) -> natural-order (T, B) entries.
+# --------------------------------------------------------------------------
+
+def _sub_words(words: jnp.ndarray, bits: int):
+    """(T, n) int32 -> the 32/bits sign-extended sub-word planes, low first."""
+    return [
+        jax.lax.shift_right_arithmetic(
+            jax.lax.shift_left(words, jnp.int32(32 - (q + 1) * bits)),
+            jnp.int32(32 - bits),
+        )
+        for q in range(32 // bits)
+    ]
+
+
+def _decode_val_words(vw: jnp.ndarray, fmt: ValueFormat) -> jnp.ndarray:
+    """One value section's (T, Wv) int32 words -> (T, B) f32 values.
+
+    Narrow values are planar within the section (``bscsr.fuse_words``):
+    plane ``q`` (sub-word ``q`` of every word) holds a contiguous run of
+    entries, so concatenating the planes restores stream order.
+    """
     if fmt.storage_dtype == "float32":
         return jax.lax.bitcast_convert_type(vw, jnp.float32)
-    if fmt.storage_dtype == "bfloat16":
-        v = jax.lax.bitcast_convert_type(vw, jnp.bfloat16).reshape(tb)
-        return v.astype(jnp.float32)
-    if fmt.storage_dtype == "int16":
-        v = jax.lax.bitcast_convert_type(vw, jnp.int16).reshape(tb)
-        return v.astype(jnp.float32) * jnp.float32(fmt.scale)
-    # int8: four lanes per word
-    v = jax.lax.bitcast_convert_type(vw, jnp.int8).reshape(tb)
-    return v.astype(jnp.float32) * jnp.float32(fmt.scale)
+    if fmt.storage_dtype == "bfloat16":      # bf16 = the top half of an f32
+        lo = jax.lax.shift_left(vw, jnp.int32(16))
+        hi = jnp.bitwise_and(vw, jnp.int32(-65536))
+        planes = [jax.lax.bitcast_convert_type(p, jnp.float32) for p in (lo, hi)]
+        return jnp.concatenate(planes, axis=1)
+    bits = 16 if fmt.storage_dtype == "int16" else 8
+    v = jnp.concatenate(_sub_words(vw, bits), axis=1).astype(jnp.float32)
+    return v * jnp.float32(fmt.scale)
 
 
-def _decode_fused_tile(
-    words, block: int, fmt, col_words: int
-):
-    """Bit-exact decode of one fused tile ref: (1, T, W) -> (flag words, c, v).
+def _unpack_flags(fw: jnp.ndarray) -> jnp.ndarray:
+    """(T, B/32) packed row-start words -> (T, B) int32 {0, 1} bits."""
+    shifts = _iota((1, FLAG_WORD_BITS), 1)
+    return jnp.concatenate(
+        [
+            jnp.bitwise_and(jax.lax.shift_right_arithmetic(fw[:, w : w + 1], shifts), 1)
+            for w in range(fw.shape[1])
+        ],
+        axis=1,
+    )
 
-    Sections per packet row are ``flags | cols | vals`` (bscsr.fuse_stream);
-    sub-words are little-endian, so value ``2i`` sits in the low half of word
-    ``i`` — which is exactly ``lax.bitcast_convert_type``'s narrow-dtype
-    layout (int32 (N,) -> int16 (N, 2) / int8 (N, 4) / bf16 (N, 2)), so one
-    bitcast recovers each section instead of a shift/mask/interleave chain
-    (the shift form, e.g. ``(w << 16) >> 16`` for the low int16, is the
-    fallback if a backend lacks narrow bitcasts).  Returns the packed flag
-    words (T, B/32) plus int32 cols and f32 values of length T*B —
-    bit-identical to reading the split arrays.
+
+def _decode_fused(words_ref, block: int, fmt, col_words: int):
+    """One fused tile ref (T, W) -> (v f32, c int32, f int32), each (T, B).
 
     ``fmt`` may be a :class:`TaggedFormatClass` (mixed-precision snapshots):
-    the packet rows then lead with one header word carrying the partition's
-    format code, sections shift right by one word, and — where the class has
-    several members sharing a storage width (BF16 vs Q15 in the 2-byte
-    class) — the value section is decoded each way and the header tag
-    selects per core at run time.
+    packet rows then lead with one header word carrying the partition's
+    format code; where the class has several members sharing a storage width
+    (BF16 vs Q15 in the 2-byte class) the value section is decoded each way
+    and the header tag selects per packet row at run time.
     """
-    t = words.shape[1]
-    tb = t * block
-    wf = block // FLAG_WORD_BITS
     tagged = isinstance(fmt, TaggedFormatClass)
     h = 1 if tagged else 0
-    # Static sub-range loads of the one streamed block ref (no full-block
-    # materialize + copy-slices: each section is read exactly once).
-    flag_words = words[0, :, h : h + wf]
-    cw = words[0, :, h + wf : h + wf + col_words].reshape(-1)
-    vw = words[0, :, h + wf + col_words :].reshape(-1)
+    wf = block // FLAG_WORD_BITS
+    f = _unpack_flags(words_ref[:, h : h + wf])
+    cw = words_ref[:, h + wf : h + wf + col_words]
+    vw = words_ref[:, h + wf + col_words :]
+    c = cw if col_words == block else jnp.concatenate(_sub_words(cw, 16), axis=1)
+    members = fmt.member_formats if tagged else (fmt,)
+    v = _decode_val_words(vw, members[0])
+    if len(members) > 1:
+        tag = words_ref[:, 0:1]                  # (T, 1): every row carries it
+        for m in members[1:]:
+            v = jnp.where(tag == m.code, _decode_val_words(vw, m), v)
+    return v, c, f
 
-    if col_words == block:                       # int32 col ids: words verbatim
-        c = cw
-    else:   # int16 pairs (ids < 2**15; the gather consumes int16 directly)
-        c = jax.lax.bitcast_convert_type(cw, jnp.int16).reshape(tb)
 
-    if not tagged:
-        return flag_words, c, _decode_val_words(vw, fmt, tb)
+def _decode_split(vals_ref, cols_ref, flags_ref, fmt: ValueFormat):
+    v = vals_ref[...].astype(jnp.float32)
+    if fmt.is_fixed_point:
+        v = v * jnp.float32(fmt.scale)
+    return v, cols_ref[...].astype(jnp.int32), _unpack_flags(flags_ref[...])
 
-    members = fmt.member_formats
-    if len(members) == 1:
-        return flag_words, c, _decode_val_words(vw, members[0], tb)
-    # Shared-width class: the header tag is load-bearing — decode the value
-    # words under every member format and let the core's tag pick one.
-    tag = words[0, 0, 0]
-    v = _decode_val_words(vw, members[0], tb)
-    for m in members[1:]:
-        v = jnp.where(tag == m.code, _decode_val_words(vw, m, tb), v)
-    return flag_words, c, v
 
+def _decode_tile(stream_refs, layout: str, block: int, fmt, col_words: int):
+    if layout == "fused":
+        return _decode_fused(stream_refs[0], block, fmt, col_words)
+    return _decode_split(*stream_refs, fmt)
+
+
+# --------------------------------------------------------------------------
+# Stages 1-3 (shared by every kernel) and the stage-4 k-pass.
+# --------------------------------------------------------------------------
 
 def _gather_x(x: jnp.ndarray, c: jnp.ndarray, gather_mode: str) -> jnp.ndarray:
-    """Stage-1 x-gather with explicit clip+mask out-of-range semantics.
-
-    Padding/sentinel stream entries carry zero values but arbitrary col ids;
-    clipping the gather and zeroing out-of-range lanes keeps the result
-    defined (and NaN-free) whatever the padding left behind, on x of shape
-    (M,) or a (Q, M) batch (gathered along the last axis).
-    """
-    m = x.shape[-1]
-    oob = (c < 0) | (c >= m)
+    """x (Q, M) at cols c (1, E) -> (Q, E); out-of-range ids gather 0."""
+    m = x.shape[1]
     if gather_mode == "onehot":
-        # MXU-gather: one-hot(cols) @ x; oob lanes get an all-zero one-hot row.
-        sel = (c[:, None] == jnp.arange(m, dtype=jnp.int32)[None, :])
-        sel = sel.astype(jnp.float32)
-        if x.ndim == 2:                                        # (Q, M) -> (Q, TB)
-            return jnp.dot(x, sel.T, preferred_element_type=jnp.float32)
-        return jnp.dot(sel, x, preferred_element_type=jnp.float32)
-    xv = jnp.take(x, jnp.clip(c, 0, m - 1), axis=x.ndim - 1)
-    return jnp.where(oob if x.ndim == 1 else oob[None, :], 0.0, xv)
+        sel = (_iota((m, c.shape[1]), 0) == c).astype(jnp.float32)   # (M, E)
+        return jnp.dot(x, sel, precision=_HIGHEST, preferred_element_type=jnp.float32)
+    oob = (c < 0) | (c >= m)
+    xv = jnp.take(x, jnp.clip(c[0], 0, m - 1), axis=1)
+    return jnp.where(oob, 0.0, xv)
 
 
-def _segment_sums_onehot(prods: jnp.ndarray, seg: jnp.ndarray, tb: int) -> jnp.ndarray:
-    """Legacy O(TB^2) segmented sum: (..., TB) @ one-hot(TB, TB+1) on the MXU."""
-    seg_ids = jnp.arange(tb + 1, dtype=jnp.int32)
-    onehot = (seg[:, None] == seg_ids[None, :]).astype(jnp.float32)
-    if prods.ndim == 1:
-        return jnp.dot(prods[None, :], onehot, preferred_element_type=jnp.float32)[0]
-    return jnp.dot(prods, onehot, preferred_element_type=jnp.float32)
+def _step_candidates(x, v, c, f, carry_row, carry_sum, *, gather_mode, prefix_sums):
+    """Stages 1-3 of one grid step.
 
-
-def _segment_sums_linear(
-    prods: jnp.ndarray, f: jnp.ndarray, seg: jnp.ndarray, tb: int
-) -> jnp.ndarray:
-    """O(TB) segmented sum: prefix-sum of products, differenced at boundaries.
-
-    ``ends[s]`` holds the inclusive prefix sum at the last element of segment
-    ``s`` (each segment has exactly one last element, so the scatter indices
-    are unique; non-last elements are parked in a discarded overflow slot).
-    Segment sums are then first differences of ``ends``.  An empty carry
-    segment 0 (packet starts with a row boundary) correctly stays 0.
+    Returns the step's candidates in segment space — ``cand_v`` (Q, S),
+    ``cand_r`` (1, S) slot ids and ``complete`` (1, S) — plus the slot id of
+    segment 0, and advances the carry scratch.  Segment 0 continues the row
+    left open by the previous step; the step's last segment stays open.
     """
-    is_last = jnp.concatenate([f[1:], jnp.ones((1,), f.dtype)]) == 1
-    slot = jnp.where(is_last, seg, tb + 1)            # overflow slot discarded
-    ps = jnp.cumsum(prods, axis=-1)
-    if prods.ndim == 1:
-        ends = jnp.zeros((tb + 2,), jnp.float32).at[slot].set(ps)[: tb + 1]
-        prev = jnp.concatenate([jnp.zeros((1,), jnp.float32), ends[:-1]])
+    e = v.shape[0] * v.shape[1]
+    v, c, f = v.reshape(1, e), c.reshape(1, e), f.reshape(1, e)
+    s_pad = -(-(e + 1) // LANES) * LANES          # >= E+1 segment slots
+
+    # ---- stage 1: gather x, multiply ----
+    prods = v * _gather_x(x, c, gather_mode)                          # (Q, E)
+
+    # ---- stage 2: segment ids (prefix count of flags) and segment sums ----
+    tri = (_iota((e, e), 0) <= _iota((e, e), 1)).astype(jnp.float32)  # i <= j
+    seg = jnp.dot(
+        f.astype(jnp.bfloat16), tri.astype(jnp.bfloat16),             # exact 0/1
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)                                               # (1, E)
+    onehot = (_iota((s_pad, e), 0) == seg).astype(jnp.float32)        # (S, E)
+    if prefix_sums:
+        ps = jnp.dot(prods, tri, precision=_HIGHEST, preferred_element_type=jnp.float32)
+        is_last = jnp.concatenate([f[:, 1:], jnp.ones((1, 1), f.dtype)], axis=1) == 1
+        ends = _dot_nt(jnp.where(is_last, ps, 0.0), onehot)           # prefix at each end
+        prev = jnp.concatenate([jnp.zeros((ends.shape[0], 1), jnp.float32), ends[:, :-1]], axis=1)
+        seg_sums = ends - prev
     else:
-        q = prods.shape[0]
-        ends = jnp.zeros((q, tb + 2), jnp.float32).at[:, slot].set(ps)[:, : tb + 1]
-        prev = jnp.concatenate([jnp.zeros((q, 1), jnp.float32), ends[:, :-1]], axis=-1)
-    return ends - prev
+        seg_sums = _dot_nt(prods, onehot)                             # (Q, S)
+
+    # ---- stage 3: cross-step carry (paper's new_row / last_packet_output) ----
+    s_last = jnp.sum(f)                          # id of the step's open segment
+    sid = _iota((1, s_pad), 1)
+    row0 = carry_row[0]
+    part = carry_sum[...]                                             # (Q, 1)
+    cand_v = seg_sums + jnp.where(sid == 0, part, 0.0)
+    cand_r = row0 + sid
+    complete = (sid < s_last) & (cand_r >= 0)
+    carry_row[0] = row0 + s_last
+    carry_sum[...] = (
+        jnp.sum(jnp.where(sid == s_last, seg_sums, 0.0), axis=1, keepdims=True)
+        + jnp.where(s_last == 0, part, 0.0)
+    )
+    return cand_v, cand_r, complete, row0
 
 
-def _scratch_update_kpass(pool_v, pool_r, k: int):
-    """Legacy k-pass masked max-extract over the full (k + TB + 1) pool."""
-    new_v, new_r = [], []
-    for _ in range(k):  # unrolled; k is small (paper uses k = 8)
-        i = jnp.argmax(pool_v)
-        new_v.append(pool_v[i])
-        new_r.append(pool_r[i])
-        pool_v = pool_v.at[i].set(NEG_INF)
-    return jnp.stack(new_v), jnp.stack(new_r)
+def _kpass(pool_v: jnp.ndarray, pool_r: jnp.ndarray, k: int):
+    """k passes of max + first-argmax over (Q, P) pools -> sorted (Q, k)."""
+    q = pool_v.shape[0]
+    lane = _iota(pool_v.shape, 1)
+    slot = _iota((q, k), 1)
+    out_v = jnp.full((q, k), NEG_INF, jnp.float32)
+    out_r = jnp.zeros((q, k), jnp.int32)
+    for j in range(k):       # unrolled; k is small (paper uses k = 8)
+        mv = jnp.max(pool_v, axis=1, keepdims=True)
+        first = jnp.min(jnp.where(pool_v == mv, lane, pool_v.shape[1]), axis=1, keepdims=True)
+        hit = lane == first
+        mr = jnp.sum(jnp.where(hit, pool_r, 0), axis=1, keepdims=True)
+        out_v = jnp.where(slot == j, mv, out_v)
+        out_r = jnp.where(slot == j, mr, out_r)
+        pool_v = jnp.where(hit, -jnp.inf, pool_v)  # taken: below every sentinel
+    return out_v, out_r
 
 
-def _scratch_update_threshold(acc_v, acc_r, cand_v, cand_r, k: int):
-    """Threshold-filter + single top-k merge (paper's scratchpad admission).
+# --------------------------------------------------------------------------
+# Kernels.
+# --------------------------------------------------------------------------
 
-    Candidates not exceeding the running k-th value cannot enter the
-    scratchpad (on ties the incumbent wins, matching the k-pass argmax
-    tie-break), so they are masked before one stable ``lax.top_k`` picks the
-    <=k survivors; a second 2k-wide top-k merges them with the scratchpad.
-    """
-    thr = jnp.min(acc_v)
-    fv = jnp.where(cand_v > thr, cand_v, NEG_INF)
-    cv, ci = jax.lax.top_k(fv, k)                     # stable: row order on ties
-    cr = jnp.take(cand_r, ci)
-    pool_v = jnp.concatenate([acc_v, cv])
-    pool_r = jnp.concatenate([acc_r, cr.astype(jnp.int32)])
-    mv, mi = jax.lax.top_k(pool_v, k)                 # scratchpad first on ties
-    return mv, jnp.take(pool_r, mi)
-
-
-def _split_stage1(vals_ref, cols_ref, tb: int, fmt: ValueFormat):
-    """Legacy three-array stage-1 load: dequantize vals; cols stay at storage
-    width (the gather consumes int16/int32 ids directly)."""
-    v = vals_ref[...].reshape(tb)
-    if fmt.is_fixed_point:
-        v = v.astype(jnp.float32) * jnp.float32(fmt.scale)
-    else:
-        v = v.astype(jnp.float32)
-    return v, cols_ref[...].reshape(tb)
-
-
-def _topk_spmv_kernel(
-    x_ref,            # (M,) f32                      VMEM (URAM analogue)
-    *refs,            # split: vals (1,T,B), cols (1,T,B), flags (1,T,B//32)
-                      # fused: words (1,T,W) int32 — ONE contiguous stream
-                      # then outputs topv (1,k) f32, topr (1,k) int32 and
-                      # scratch acc_v (k,) f32, acc_r (k,) i32,
-                      # carry_row (1,) i32 SMEM, carry_sum (1,) f32 SMEM
+def _topk_kernel(
+    x_ref,            # (Q, M) f32 query batch (URAM analogue)
+    *refs,            # stream refs (1 fused or 3 split), outputs topv/topr
+                      # (Q, k), scratch acc_v/acc_r (Q, k), carry_row (1,)
+                      # SMEM, carry_sum (Q, 1) VMEM
+    n_streams: int,
     k: int,
     n_rows: int,
     num_steps: int,
-    fmt: ValueFormat,
+    fmt,
     gather_mode: str,
     inner_loop: str,
     stream_layout: str,
     block: int,
     col_words: int,
 ):
-    if stream_layout == "fused":
-        words_ref, topv_ref, topr_ref, acc_v, acc_r, carry_row, carry_sum = refs
-        num_t = words_ref.shape[1]
-    else:
-        (vals_ref, cols_ref, flags_ref, topv_ref, topr_ref,
-         acc_v, acc_r, carry_row, carry_sum) = refs
-        num_t = vals_ref.shape[1]
-    linear_seg, linear_topk = _inner_loop_flags(inner_loop)
+    streams = refs[:n_streams]
+    topv_ref, topr_ref, acc_v, acc_r, carry_row, carry_sum = refs[n_streams:]
+    prefix_sums, gated = _inner_loop_flags(inner_loop)
     step = pl.program_id(1)
+    q = x_ref.shape[0]
 
     # -- per-core reset (each grid-dim-0 core owns an independent partition) --
     @pl.when(step == 0)
     def _init():
-        acc_v[...] = jnp.full((k,), NEG_INF, jnp.float32)
-        acc_r[...] = jnp.full((k,), n_rows, jnp.int32)
+        acc_v[...] = jnp.full((q, k), NEG_INF, jnp.float32)
+        acc_r[...] = jnp.full((q, k), n_rows, jnp.int32)
         carry_row[0] = -1
-        carry_sum[0] = 0.0
+        carry_sum[...] = jnp.zeros((q, 1), jnp.float32)
 
-    tb = num_t * block
-
-    # ---- stage 1: load packet(s), decode, gather x, multiply ----
-    if stream_layout == "fused":
-        flag_words, c, v = _decode_fused_tile(words_ref, block, fmt, col_words)
-    else:
-        v, c = _split_stage1(vals_ref, cols_ref, tb, fmt)
-        flag_words = flags_ref[...]
-    x = x_ref[...].astype(jnp.float32)
-    prods = v * _gather_x(x, c, gather_mode)
-
-    # ---- stage 2: row-aggregate (segmented sum, O(TB) by default) ----
-    f = _unpack_flags_tile(flag_words, tb)
-    seg = jnp.cumsum(f)                         # (tb,) segment id, 0 = carry row
-    s_last = seg[-1]
-    seg_ids = jnp.arange(tb + 1, dtype=jnp.int32)
-    if linear_seg:
-        seg_sums = _segment_sums_linear(prods, f, seg, tb)
-    else:
-        seg_sums = _segment_sums_onehot(prods, seg, tb)
-
-    # ---- stage 3: cross-packet carry (paper's new_row / last_packet_output) --
-    row0 = carry_row[0]
-    part = carry_sum[0]
-    cand_v = seg_sums + jnp.where(seg_ids == 0, part, 0.0)
-    cand_r = row0 + seg_ids
-    complete = (seg_ids < s_last) & (cand_r >= 0)  # last segment stays open
+    v, c, f = _decode_tile(streams, stream_layout, block, fmt, col_words)
+    cand_v, cand_r, complete, _ = _step_candidates(
+        x_ref[...].astype(jnp.float32), v, c, f, carry_row, carry_sum,
+        gather_mode=gather_mode, prefix_sums=prefix_sums,
+    )
     cand_v = jnp.where(complete, cand_v, NEG_INF)
-    carry_row[0] = row0 + s_last
-    carry_sum[0] = seg_sums[s_last] + jnp.where(s_last == 0, part, 0.0)
 
     # ---- stage 4: top-k scratchpad update ----
-    if linear_topk:
-        mv, mr = _scratch_update_threshold(
-            acc_v[...], acc_r[...], cand_v, cand_r.astype(jnp.int32), k
-        )
+    def _update():
+        pool_v = jnp.concatenate([acc_v[...], cand_v], axis=1)
+        pool_r = jnp.concatenate([acc_r[...], jnp.broadcast_to(cand_r, cand_v.shape)], axis=1)
+        acc_v[...], acc_r[...] = _kpass(pool_v, pool_r, k)
+
+    if gated:   # admission test: skip unless a candidate beats the k-th value
+        thr = jnp.min(acc_v[...], axis=1, keepdims=True)
+        pl.when(jnp.any(cand_v > thr))(_update)
     else:
-        pool_v = jnp.concatenate([acc_v[...], cand_v])
-        pool_r = jnp.concatenate([acc_r[...], cand_r.astype(jnp.int32)])
-        mv, mr = _scratch_update_kpass(pool_v, pool_r, k)
-    acc_v[...] = mv
-    acc_r[...] = mr
+        _update()
 
     # ---- emit the core's k candidates on its final step ----
     @pl.when(step == num_steps - 1)
     def _emit():
-        topv_ref[...] = acc_v[...].reshape(1, k)
-        topr_ref[...] = acc_r[...].reshape(1, k)
+        topv_ref[...] = acc_v[...]
+        topr_ref[...] = acc_r[...]
 
+
+# Accumulate mode (beyond-paper): y = A @ x without the top-k select stage.
+#
+# Iterative graph workloads (PPR, power-iteration eigensolvers) run the SAME
+# packet stream but keep every row's score: stages 1-3 are identical, and
+# stage 4's scratchpad is replaced by a dense per-core accumulator of one f32
+# per slot.  A step's completed rows are the consecutive slots row0 .. row0 +
+# s_last - 1, so one masked one-hot matmul places the step's segment sums at
+# their lane offset inside a 128-aligned window of the accumulator.  Each row
+# completes exactly once, so the add never mixes two rows.  The open trailing
+# sentinel row never completes, so padding packets and bucketed slot budgets
+# add nothing.  alpha/beta scaling, tombstone masking and the slot->global-row
+# scatter live in the jnp epilogue (``ops.scatter_slot_sums``).
+
+def _accum_kernel(
+    x_ref,            # (Q, M) f32; row 0 is the query (see _query_rows)
+    *refs,            # stream refs, output y (1, n_rows), scratch y_acc
+                      # (1, L) VMEM, carry_row (1,) SMEM, carry_sum (Q, 1) VMEM
+    n_streams: int,
+    n_rows: int,
+    num_steps: int,
+    fmt,
+    gather_mode: str,
+    inner_loop: str,
+    stream_layout: str,
+    block: int,
+    col_words: int,
+):
+    streams = refs[:n_streams]
+    y_ref, y_acc, carry_row, carry_sum = refs[n_streams:]
+    prefix_sums, _ = _inner_loop_flags(inner_loop)  # stage 4 has no variants here
+    step = pl.program_id(1)
+
+    @pl.when(step == 0)
+    def _init():
+        y_acc[...] = jnp.zeros(y_acc.shape, jnp.float32)
+        carry_row[0] = -1
+        carry_sum[...] = jnp.zeros(carry_sum.shape, jnp.float32)
+
+    v, c, f = _decode_tile(streams, stream_layout, block, fmt, col_words)
+    cand_v, _, complete, row0 = _step_candidates(
+        x_ref[...].astype(jnp.float32), v, c, f, carry_row, carry_sum,
+        gather_mode=gather_mode, prefix_sums=prefix_sums,
+    )
+    # ---- stage 4': place segment s at slot row0 + s (row0 >= -1) ----
+    s_pad = cand_v.shape[1]
+    width = s_pad + LANES
+    base = (jnp.maximum(row0, 0) // LANES) * LANES
+    place = _iota((s_pad, width), 1) == _iota((s_pad, width), 0) + (row0 - base)
+    window = jnp.dot(
+        jnp.where(complete, cand_v[:1], 0.0), place.astype(jnp.float32),
+        precision=_HIGHEST, preferred_element_type=jnp.float32,
+    )
+    base = pl.multiple_of(base, LANES)
+    y_acc[:, pl.ds(base, width)] += window
+
+    @pl.when(step == num_steps - 1)
+    def _emit():
+        y_ref[...] = y_acc[:, :n_rows]
+
+
+# --------------------------------------------------------------------------
+# Wrappers.
+# --------------------------------------------------------------------------
 
 def _fused_geometry(width: int, block: int, fmt) -> int:
     """Validate a fused stream width and return its col-section word count.
@@ -397,27 +452,69 @@ def _fused_geometry(width: int, block: int, fmt) -> int:
     return col_words
 
 
-def _stream_specs(stream_layout: str, t: int, block: int, width: int):
-    """BlockSpecs for the matrix stream(s): one fused block or three split."""
+def _stream_operands(vals, cols, flags, *, fmt_name, stream_layout, block_size,
+                     packets_per_step, gather_mode, interpret):
+    """Validate the knobs; -> (static kernel kwargs, 4-D streams, specs, grid)."""
+    fmt = STREAM_FORMATS[fmt_name]
+    if isinstance(fmt, TaggedFormatClass) and stream_layout != "fused":
+        raise ValueError(
+            f"tagged format class {fmt_name!r} requires stream_layout='fused'"
+        )
+    _check_gather_mode(gather_mode, interpret)
+    n_cores, n_packets, last = vals.shape
     if stream_layout == "fused":
-        return [pl.BlockSpec((1, t, width), lambda c, i: (c, i, 0))]
-    w = block // FLAG_WORD_BITS
-    return [
-        pl.BlockSpec((1, t, block), lambda c, i: (c, i, 0)),
-        pl.BlockSpec((1, t, block), lambda c, i: (c, i, 0)),
-        pl.BlockSpec((1, t, w), lambda c, i: (c, i, 0)),
+        if block_size is None:
+            raise ValueError("stream_layout='fused' requires block_size")
+        block = block_size
+        col_words = _fused_geometry(last, block, fmt)
+        streams = (vals,)
+    elif stream_layout == "split":
+        block, col_words = last, 0
+        streams = (vals, cols, flags)
+    else:
+        raise ValueError(f"stream_layout must be 'split' or 'fused', got {stream_layout!r}")
+    t = packets_per_step
+    if n_packets % t:
+        raise ValueError(
+            f"packet count {n_packets} is not a multiple of packets_per_step={t}"
+        )
+    num_steps = n_packets // t
+    streams = tuple(s.reshape(n_cores, num_steps, t, s.shape[-1]) for s in streams)
+    specs = [
+        pl.BlockSpec((None, None, t, s.shape[-1]), lambda c, i: (c, i, 0, 0))
+        for s in streams
     ]
+    static = dict(
+        n_streams=len(streams), num_steps=num_steps, fmt=fmt,
+        gather_mode=gather_mode, stream_layout=stream_layout, block=block,
+        col_words=col_words,
+    )
+    return static, streams, specs, (n_cores, num_steps)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "k", "n_rows", "packets_per_step", "fmt_name", "gather_mode",
-        "inner_loop", "stream_layout", "block_size", "interpret",
-    ),
+def _query_rows(x: jnp.ndarray, interpret: bool) -> jnp.ndarray:
+    """The (Q, M) f32 query block the kernel runs on.
+
+    XLA:CPU rewrites a one-row matmul as a fused multiply-reduce whose
+    summation order follows the producer fusion, so the two stream layouts
+    could then differ in the last bit; interpreted runs therefore carry a
+    duplicate of a lone query row (Mosaic runs it as given).
+    """
+    x = x.astype(jnp.float32)
+    if interpret and x.shape[0] == 1:
+        return jnp.concatenate([x, x])
+    return x
+
+
+_TOPK_STATICS = (
+    "k", "n_rows", "packets_per_step", "fmt_name", "gather_mode",
+    "inner_loop", "stream_layout", "block_size", "interpret",
 )
-def bscsr_topk_spmv(
-    x: jnp.ndarray,        # (M,) float32 query embedding
+
+
+@functools.partial(jax.jit, static_argnames=_TOPK_STATICS)
+def bscsr_topk_spmv_multiquery(
+    x: jnp.ndarray,        # (Q, M) float32 query batch
     vals: jnp.ndarray,     # split: (C, P, B) storage dtype; fused: (C, P, W) i32
     cols: jnp.ndarray = None,   # (C, P, B) int16/int32 (split only)
     flags: jnp.ndarray = None,  # (C, P, B//32) int32   (split only)
@@ -426,171 +523,79 @@ def bscsr_topk_spmv(
     n_rows: int,           # per-core slot budget (uniform; may be a bucketed
                            # pad of the live count — see the scratch-shape
                            # analysis in the module docstring)
+    interpret: bool,
     packets_per_step: int = 2,
     fmt_name: str = "F32",
-    gather_mode: str = "take",
+    gather_mode: str = "onehot",
     inner_loop: str = "linear",
     stream_layout: str = "split",
     block_size: int = None,  # required for "fused" (W hides B); ignored otherwise
-    interpret: bool = True,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Run the multi-core kernel; returns per-core (vals, local rows), (C, k).
+):
+    """Q queries share one stream pass; returns per-core (vals, rows), (C, Q, k).
 
     With ``stream_layout="fused"`` pass the ``bscsr.fuse_stream`` word array
-    as ``vals`` (``cols``/``flags`` stay ``None``): each grid step then
-    pipelines ONE contiguous block instead of three.
-
-    ``fmt_name`` may also name a tagged width class (``TAG4``/``TAG2``/
-    ``TAG1``) for one group of a mixed-precision snapshot — fused layout
-    only, since the per-packet header tag lives in the fused word stream.
+    as ``vals`` (``cols``/``flags`` stay ``None``).  ``fmt_name`` may also
+    name a tagged width class (``TAG4``/``TAG2``/``TAG1``) for one group of a
+    mixed-precision snapshot — fused layout only.  ``interpret`` is required:
+    ``False`` compiles with Mosaic, ``True`` runs the Pallas interpreter.
     """
-    fmt = STREAM_FORMATS[fmt_name]
-    if isinstance(fmt, TaggedFormatClass) and stream_layout != "fused":
-        raise ValueError(
-            f"tagged format class {fmt_name!r} requires stream_layout='fused'"
-        )
-    n_cores, n_packets, last = vals.shape
-    if stream_layout == "fused":
-        if block_size is None:
-            raise ValueError("stream_layout='fused' requires block_size")
-        block, width = block_size, last
-        col_words = _fused_geometry(width, block, fmt)
-        streams = (vals,)
-    else:
-        block, width = last, last
-        col_words = 0
-        streams = (vals, cols, flags)
-    t = packets_per_step
-    assert n_packets % t == 0, "pad packet count to a multiple of packets_per_step"
-    num_steps = n_packets // t
-
-    kernel = functools.partial(
-        _topk_spmv_kernel,
-        k=k,
-        n_rows=n_rows,
-        num_steps=num_steps,
-        fmt=fmt,
-        gather_mode=gather_mode,
-        inner_loop=inner_loop,
-        stream_layout=stream_layout,
-        block=block,
-        col_words=col_words,
+    static, streams, specs, grid = _stream_operands(
+        vals, cols, flags, fmt_name=fmt_name, stream_layout=stream_layout,
+        block_size=block_size, packets_per_step=packets_per_step,
+        gather_mode=gather_mode, interpret=interpret,
     )
-    grid = (n_cores, num_steps)
-    return pl.pallas_call(
+    nq = x.shape[0]
+    x = _query_rows(x, interpret)
+    rows, m = x.shape
+    kernel = functools.partial(
+        _topk_kernel, k=k, n_rows=n_rows, inner_loop=inner_loop, **static
+    )
+    out_spec = pl.BlockSpec((None, rows, k), lambda c, i: (c, 0, 0))
+    v, r = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((x.shape[0],), lambda c, i: (0,)),
-            *_stream_specs(stream_layout, t, block, width),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda c, i: (c, 0)),
-            pl.BlockSpec((1, k), lambda c, i: (c, 0)),
-        ],
+        in_specs=[pl.BlockSpec((rows, m), lambda c, i: (0, 0)), *specs],
+        out_specs=[out_spec, out_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((n_cores, k), jnp.float32),
-            jax.ShapeDtypeStruct((n_cores, k), jnp.int32),
+            jax.ShapeDtypeStruct((grid[0], rows, k), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], rows, k), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((k,), jnp.float32),
-            pltpu.VMEM((k,), jnp.int32),
+            pltpu.VMEM((rows, k), jnp.float32),
+            pltpu.VMEM((rows, k), jnp.int32),
             pltpu.SMEM((1,), jnp.int32),
-            pltpu.SMEM((1,), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x, *streams)
+    return v[:, :nq], r[:, :nq]
 
 
-# ---------------------------------------------------------------------------
-# Accumulate mode (beyond-paper): y = A @ x without the top-k select stage.
-#
-# Iterative graph workloads (PPR, power-iteration eigensolvers) run the SAME
-# packet stream but keep every row's score: stages 1-3 are identical, and
-# stage 4's k-sized scratchpad is replaced by a dense per-core accumulator of
-# one f32 per slot.  Each row completes exactly once across the whole stream
-# (stage 3 closes a segment exactly when its row-boundary flag arrives), and
-# within one step the completed segment ids are distinct, so the scatter-add
-# indices never collide: the accumulator is a plain "write each row's sum at
-# its slot" with incomplete/carry lanes parked in a discarded overflow slot —
-# the same trick `_segment_sums_linear` uses.  The open trailing sentinel row
-# never completes, so flag-free padding packets and bucketed slot budgets add
-# exactly nothing (phantom slots stay 0.0 and are masked by the caller's
-# slot->row scatter, NOT by `finalize_candidates`, which this mode skips
-# entirely).  alpha/beta scaling, tombstone masking, and the slot->global-row
-# scatter all live in the jnp epilogue (`ops.scatter_slot_sums`) inside the
-# same jit — the kernel emits raw per-core slot sums only.
-# ---------------------------------------------------------------------------
-
-def _spmv_accum_kernel(
-    x_ref,            # (M,) f32                      VMEM (URAM analogue)
-    *refs,            # split: vals (1,T,B), cols (1,T,B), flags (1,T,B//32)
-                      # fused: words (1,T,W) int32 — ONE contiguous stream
-                      # then output y (1, n_rows) f32 and scratch
-                      # y_acc (n_rows+1,) f32 VMEM (last = overflow slot),
-                      # carry_row (1,) i32 SMEM, carry_sum (1,) f32 SMEM
+@functools.partial(jax.jit, static_argnames=_TOPK_STATICS)
+def bscsr_topk_spmv(
+    x: jnp.ndarray,        # (M,) float32 query embedding
+    vals: jnp.ndarray,
+    cols: jnp.ndarray = None,
+    flags: jnp.ndarray = None,
+    *,
+    k: int,
     n_rows: int,
-    num_steps: int,
-    fmt: ValueFormat,
-    gather_mode: str,
-    inner_loop: str,
-    stream_layout: str,
-    block: int,
-    col_words: int,
-):
-    if stream_layout == "fused":
-        words_ref, y_ref, y_acc, carry_row, carry_sum = refs
-        num_t = words_ref.shape[1]
-    else:
-        (vals_ref, cols_ref, flags_ref, y_ref,
-         y_acc, carry_row, carry_sum) = refs
-        num_t = vals_ref.shape[1]
-    linear_seg, _ = _inner_loop_flags(inner_loop)  # stage 4 has no variants here
-    step = pl.program_id(1)
-
-    @pl.when(step == 0)
-    def _init():
-        y_acc[...] = jnp.zeros((n_rows + 1,), jnp.float32)
-        carry_row[0] = -1
-        carry_sum[0] = 0.0
-
-    tb = num_t * block
-
-    # ---- stages 1-3: identical to the top-k kernel ----
-    if stream_layout == "fused":
-        flag_words, c, v = _decode_fused_tile(words_ref, block, fmt, col_words)
-    else:
-        v, c = _split_stage1(vals_ref, cols_ref, tb, fmt)
-        flag_words = flags_ref[...]
-    x = x_ref[...].astype(jnp.float32)
-    prods = v * _gather_x(x, c, gather_mode)
-
-    f = _unpack_flags_tile(flag_words, tb)
-    seg = jnp.cumsum(f)
-    s_last = seg[-1]
-    seg_ids = jnp.arange(tb + 1, dtype=jnp.int32)
-    if linear_seg:
-        seg_sums = _segment_sums_linear(prods, f, seg, tb)
-    else:
-        seg_sums = _segment_sums_onehot(prods, seg, tb)
-
-    row0 = carry_row[0]
-    part = carry_sum[0]
-    cand_v = seg_sums + jnp.where(seg_ids == 0, part, 0.0)
-    cand_r = row0 + seg_ids
-    complete = (seg_ids < s_last) & (cand_r >= 0)  # last segment stays open
-    carry_row[0] = row0 + s_last
-    carry_sum[0] = seg_sums[s_last] + jnp.where(s_last == 0, part, 0.0)
-
-    # ---- stage 4': dense accumulate — each completed row lands at its slot --
-    # `complete` implies 0 <= cand_r < n_rows, so no clip; everything else is
-    # parked in the overflow slot and discarded at emit time.
-    slot = jnp.where(complete, cand_r, n_rows).astype(jnp.int32)
-    y_acc[...] = y_acc[...].at[slot].add(jnp.where(complete, cand_v, 0.0))
-
-    @pl.when(step == num_steps - 1)
-    def _emit():
-        y_ref[...] = y_acc[:n_rows].reshape(1, n_rows)
+    interpret: bool,
+    packets_per_step: int = 2,
+    fmt_name: str = "F32",
+    gather_mode: str = "onehot",
+    inner_loop: str = "linear",
+    stream_layout: str = "split",
+    block_size: int = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """One query: the multi-query kernel at Q=1; per-core (vals, rows), (C, k)."""
+    v, r = bscsr_topk_spmv_multiquery(
+        x[None, :], vals, cols, flags, k=k, n_rows=n_rows, interpret=interpret,
+        packets_per_step=packets_per_step, fmt_name=fmt_name,
+        gather_mode=gather_mode, inner_loop=inner_loop,
+        stream_layout=stream_layout, block_size=block_size,
+    )
+    return v[:, 0], r[:, 0]
 
 
 @functools.partial(
@@ -607,247 +612,45 @@ def bscsr_spmv(
     flags: jnp.ndarray = None,  # (C, P, B//32) int32   (split only)
     *,
     n_rows: int,           # per-core slot budget (may be a bucketed pad)
+    interpret: bool,
     packets_per_step: int = 2,
     fmt_name: str = "F32",
-    gather_mode: str = "take",
+    gather_mode: str = "onehot",
     inner_loop: str = "linear",
     stream_layout: str = "split",
     block_size: int = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Accumulate-mode kernel pass: per-core dense slot sums, (C, n_rows) f32.
 
     This is ``select_topk=False``: the top-k scratchpad never runs and every
     slot's full row sum leaves the kernel.  ``inner_loop`` still selects the
-    stage-2 segmented-sum variant ("linear"/"linear-seg" -> cumsum-difference,
-    "legacy"/"linear-topk" -> one-hot matmul); the stage-4 half of each mode
-    is vacuous here.  Callers map slots to global rows, mask tombstones, and
-    apply alpha/beta via ``ops.scatter_slot_sums`` — `finalize_candidates`
-    must NOT run on this output.
+    stage-2 segmented-sum variant; the stage-4 half of each mode is vacuous
+    here.  Callers map slots to global rows, mask tombstones, and apply
+    alpha/beta via ``ops.scatter_slot_sums`` — `finalize_candidates` must NOT
+    run on this output.
     """
-    fmt = STREAM_FORMATS[fmt_name]
-    if isinstance(fmt, TaggedFormatClass) and stream_layout != "fused":
-        raise ValueError(
-            f"tagged format class {fmt_name!r} requires stream_layout='fused'"
-        )
-    n_cores, n_packets, last = vals.shape
-    if stream_layout == "fused":
-        if block_size is None:
-            raise ValueError("stream_layout='fused' requires block_size")
-        block, width = block_size, last
-        col_words = _fused_geometry(width, block, fmt)
-        streams = (vals,)
-    else:
-        block, width = last, last
-        col_words = 0
-        streams = (vals, cols, flags)
-    t = packets_per_step
-    assert n_packets % t == 0, "pad packet count to a multiple of packets_per_step"
-    num_steps = n_packets // t
-
-    kernel = functools.partial(
-        _spmv_accum_kernel,
-        n_rows=n_rows,
-        num_steps=num_steps,
-        fmt=fmt,
-        gather_mode=gather_mode,
-        inner_loop=inner_loop,
-        stream_layout=stream_layout,
-        block=block,
-        col_words=col_words,
+    static, streams, specs, grid = _stream_operands(
+        vals, cols, flags, fmt_name=fmt_name, stream_layout=stream_layout,
+        block_size=block_size, packets_per_step=packets_per_step,
+        gather_mode=gather_mode, interpret=interpret,
     )
+    e = packets_per_step * static["block"]
+    s_pad = -(-(e + 1) // LANES) * LANES
+    acc_len = -(-(n_rows + 1) // LANES) * LANES + s_pad + LANES
+    kernel = functools.partial(
+        _accum_kernel, n_rows=n_rows, inner_loop=inner_loop, **static
+    )
+    x = _query_rows(x[None, :], interpret)
     return pl.pallas_call(
         kernel,
-        grid=(n_cores, num_steps),
-        in_specs=[
-            pl.BlockSpec((x.shape[0],), lambda c, i: (0,)),
-            *_stream_specs(stream_layout, t, block, width),
-        ],
-        out_specs=[pl.BlockSpec((1, n_rows), lambda c, i: (c, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n_cores, n_rows), jnp.float32)],
+        grid=grid,
+        in_specs=[pl.BlockSpec(x.shape, lambda c, i: (0, 0)), *specs],
+        out_specs=pl.BlockSpec((None, 1, n_rows), lambda c, i: (c, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((grid[0], 1, n_rows), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((n_rows + 1,), jnp.float32),
+            pltpu.VMEM((1, acc_len), jnp.float32),
             pltpu.SMEM((1,), jnp.int32),
-            pltpu.SMEM((1,), jnp.float32),
+            pltpu.VMEM((x.shape[0], 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x, *streams)[0]
-
-
-# ---------------------------------------------------------------------------
-# Multi-query variant (beyond-paper): Q queries share one stream pass.
-#
-# The paper's design answers ONE query per pass, so intensity is capped at
-# 2 flop / (bytes-per-nnz).  Batching Q queries amortizes every packet read
-# across Q dot products: intensity scales by Q while staying memory-bound up
-# to Q ~ 500 (v5e balance point 240 flop/B over ~4 B/nnz).  §Perf C.
-#
-# The stage-2 boundary bookkeeping (flag unpack, segment ids, scatter slots)
-# is computed ONCE per packet and shared across all Q queries; only the
-# prefix sums, carries, and scratchpad updates are per-query (vectorized).
-# ---------------------------------------------------------------------------
-
-def _topk_spmv_mq_kernel(
-    x_ref,            # (Q, M) f32
-    *refs,            # split: vals (1,T,B), cols (1,T,B), flags (1,T,B//32)
-                      # fused: words (1,T,W) int32 — ONE contiguous stream
-                      # then outputs topv/topr (1,Q,k) and scratch acc_v (Q,k)
-                      # f32, acc_r (Q,k) i32, carry_row (1,) i32 SMEM,
-                      # carry_sum (Q,) f32 VMEM (per-query open-row partial)
-    k: int,
-    n_rows: int,
-    num_steps: int,
-    fmt: ValueFormat,
-    inner_loop: str,
-    stream_layout: str,
-    block: int,
-    col_words: int,
-):
-    if stream_layout == "fused":
-        words_ref, topv_ref, topr_ref, acc_v, acc_r, carry_row, carry_sum = refs
-        num_t = words_ref.shape[1]
-    else:
-        (vals_ref, cols_ref, flags_ref, topv_ref, topr_ref,
-         acc_v, acc_r, carry_row, carry_sum) = refs
-        num_t = vals_ref.shape[1]
-    linear_seg, linear_topk = _inner_loop_flags(inner_loop)
-    step = pl.program_id(1)
-    nq = x_ref.shape[0]
-
-    @pl.when(step == 0)
-    def _init():
-        acc_v[...] = jnp.full((nq, k), NEG_INF, jnp.float32)
-        acc_r[...] = jnp.full((nq, k), n_rows, jnp.int32)
-        carry_row[0] = -1
-        carry_sum[...] = jnp.zeros((nq,), jnp.float32)
-
-    tb = num_t * block
-    if stream_layout == "fused":
-        flag_words, c, v = _decode_fused_tile(words_ref, block, fmt, col_words)
-    else:
-        v, c = _split_stage1(vals_ref, cols_ref, tb, fmt)
-        flag_words = flags_ref[...]
-    xv = _gather_x(x_ref[...].astype(jnp.float32), c, "take")  # (Q, TB)
-    prods = v[None, :] * xv                                    # (Q, TB)
-
-    f = _unpack_flags_tile(flag_words, tb)
-    seg = jnp.cumsum(f)
-    s_last = seg[-1]
-    seg_ids = jnp.arange(tb + 1, dtype=jnp.int32)
-    if linear_seg:
-        seg_sums = _segment_sums_linear(prods, f, seg, tb)     # (Q, TB+1)
-    else:
-        seg_sums = _segment_sums_onehot(prods, seg, tb)
-
-    row0 = carry_row[0]
-    part = carry_sum[...]                                      # (Q,)
-    cand_v = seg_sums + jnp.where(seg_ids[None, :] == 0, part[:, None], 0.0)
-    cand_r = row0 + seg_ids
-    complete = (seg_ids < s_last) & (cand_r >= 0)
-    cand_v = jnp.where(complete[None, :], cand_v, NEG_INF)
-    carry_row[0] = row0 + s_last
-    carry_sum[...] = seg_sums[:, s_last] + jnp.where(s_last == 0, part, 0.0)
-
-    if linear_topk:
-        thr = jnp.min(acc_v[...], axis=1, keepdims=True)       # (Q, 1)
-        fv = jnp.where(cand_v > thr, cand_v, NEG_INF)
-        cv, ci = jax.lax.top_k(fv, k)                          # (Q, k)
-        cr = jnp.take(cand_r, ci).astype(jnp.int32)
-        pool_v = jnp.concatenate([acc_v[...], cv], axis=1)     # (Q, 2k)
-        pool_r = jnp.concatenate([acc_r[...], cr], axis=1)
-        mv, mi = jax.lax.top_k(pool_v, k)
-        acc_v[...] = mv
-        acc_r[...] = jnp.take_along_axis(pool_r, mi, axis=1)
-    else:
-        pool_v = jnp.concatenate([acc_v[...], cand_v], axis=1)  # (Q, k+S)
-        pool_r = jnp.concatenate(
-            [acc_r[...], jnp.broadcast_to(cand_r, (nq, tb + 1)).astype(jnp.int32)],
-            axis=1,
-        )
-        qs = jnp.arange(nq)
-        new_v, new_r = [], []
-        for _ in range(k):
-            i = jnp.argmax(pool_v, axis=1)                     # (Q,)
-            new_v.append(pool_v[qs, i])
-            new_r.append(pool_r[qs, i])
-            pool_v = pool_v.at[qs, i].set(NEG_INF)
-        acc_v[...] = jnp.stack(new_v, axis=1)
-        acc_r[...] = jnp.stack(new_r, axis=1)
-
-    @pl.when(step == num_steps - 1)
-    def _emit():
-        topv_ref[...] = acc_v[...].reshape(1, nq, k)
-        topr_ref[...] = acc_r[...].reshape(1, nq, k)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "k", "n_rows", "packets_per_step", "fmt_name", "inner_loop",
-        "stream_layout", "block_size", "interpret",
-    ),
-)
-def bscsr_topk_spmv_multiquery(
-    x: jnp.ndarray,        # (Q, M) float32 query batch
-    vals: jnp.ndarray,     # split: (C, P, B); fused: (C, P, W) int32 words
-    cols: jnp.ndarray = None,
-    flags: jnp.ndarray = None,
-    *,
-    k: int,
-    n_rows: int,
-    packets_per_step: int = 2,
-    fmt_name: str = "F32",
-    inner_loop: str = "linear",
-    stream_layout: str = "split",
-    block_size: int = None,
-    interpret: bool = True,
-):
-    """Multi-query kernel; returns per-core (vals, rows) of shape (C, Q, k)."""
-    fmt = STREAM_FORMATS[fmt_name]
-    if isinstance(fmt, TaggedFormatClass) and stream_layout != "fused":
-        raise ValueError(
-            f"tagged format class {fmt_name!r} requires stream_layout='fused'"
-        )
-    n_cores, n_packets, last = vals.shape
-    if stream_layout == "fused":
-        if block_size is None:
-            raise ValueError("stream_layout='fused' requires block_size")
-        block, width = block_size, last
-        col_words = _fused_geometry(width, block, fmt)
-        streams = (vals,)
-    else:
-        block, width = last, last
-        col_words = 0
-        streams = (vals, cols, flags)
-    nq = x.shape[0]
-    t = packets_per_step
-    assert n_packets % t == 0
-    num_steps = n_packets // t
-    kernel = functools.partial(
-        _topk_spmv_mq_kernel, k=k, n_rows=n_rows, num_steps=num_steps, fmt=fmt,
-        inner_loop=inner_loop, stream_layout=stream_layout, block=block,
-        col_words=col_words,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=(n_cores, num_steps),
-        in_specs=[
-            pl.BlockSpec((nq, x.shape[1]), lambda c, i: (0, 0)),
-            *_stream_specs(stream_layout, t, block, width),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, nq, k), lambda c, i: (c, 0, 0)),
-            pl.BlockSpec((1, nq, k), lambda c, i: (c, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_cores, nq, k), jnp.float32),
-            jax.ShapeDtypeStruct((n_cores, nq, k), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((nq, k), jnp.float32),
-            pltpu.VMEM((nq, k), jnp.int32),
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.VMEM((nq,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x, *streams)
+    )(x, *streams)[:, 0]

@@ -297,16 +297,15 @@ class QueryExecutor:
         packets_per_step: int = 2,
         gather_mode: str = "auto",
         inner_loop: str = "linear",
-        interpret: bool = True,
+        interpret: Optional[bool] = None,
         q_bucketing: bool = True,
     ):
         self.big_k = big_k
         self.k = k
         self.packets_per_step = packets_per_step
-        # "auto" must resolve eagerly: the microbench cannot run under trace.
         self.gather_mode = ops.resolve_gather_mode(gather_mode)
         self.inner_loop = inner_loop
-        self.interpret = interpret
+        self.interpret = ops.resolve_interpret(interpret)
         self.q_bucketing = q_bucketing
         self._fns: dict = {}
         self._pinned: set = set()  # (uid, layout) keys this executor touched
@@ -498,6 +497,9 @@ class QueryExecutor:
             "q_exact_hits": self.q_exact_hits,          # exact-bucket fn reuse
             "device_snapshots": len(self._pinned),      # this executor's pins
             "device_snapshots_process_wide": device_cache_size(),
+            "interpret": self.interpret,                # False: Mosaic-compiled
+            "gather_mode": self.gather_mode,
+            "paths": sorted({key[0] for key in self._fns}),  # compiled fn paths
         }
 
     # -- compilation ---------------------------------------------------------
@@ -553,12 +555,10 @@ class QueryExecutor:
             kwargs = dict(
                 k=k, n_rows=max_slots,
                 packets_per_step=self.packets_per_step,
-                fmt_name=snap.fmt_name, inner_loop=self.inner_loop,
-                stream_layout=layout, block_size=snap.block_size,
-                interpret=self.interpret,
+                fmt_name=snap.fmt_name, gather_mode=self.gather_mode,
+                inner_loop=self.inner_loop, stream_layout=layout,
+                block_size=snap.block_size, interpret=self.interpret,
             )
-            if q is None:
-                kwargs["gather_mode"] = self.gather_mode
 
             if snap.groups_meta is not None:
                 # Mixed precision: one kernel call per width class over its
@@ -859,6 +859,10 @@ class ShardedDeviceBundle:
             fam["global"] = self._assemble(fam)
         return fam["global"]
 
+    def placement(self, name: str) -> dict:
+        """device -> shard block held there, for one family (e.g. "words")."""
+        return dict(self._fams[name]["devmap"])
+
     def sync_replicated(self, name: str, value: np.ndarray, version) -> jax.Array:
         """A fully replicated (every device) global array for small metadata
         like the traced global row-id sentinel."""
@@ -901,16 +905,16 @@ def get_executor(
     packets_per_step: int = 2,
     gather_mode: str = "auto",
     inner_loop: str = "linear",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> QueryExecutor:
     """Process-wide interned executor for one set of query knobs.
 
-    ``gather_mode="auto"`` is resolved (measured) BEFORE interning, so
-    ``auto`` and its resolution share one executor.
+    ``gather_mode`` and ``interpret`` are resolved BEFORE interning, so
+    ``"auto"``/``None`` and their resolutions share one executor.
     """
     return _interned_executor(
         big_k, k, packets_per_step, ops.resolve_gather_mode(gather_mode),
-        inner_loop, bool(interpret),
+        inner_loop, ops.resolve_interpret(interpret),
     )
 
 

@@ -55,7 +55,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
-import time
 import weakref
 from typing import Optional, Sequence, Tuple
 
@@ -808,66 +807,32 @@ def _finalize_kwargs(packed: PackedPartitions) -> dict:
     return kw
 
 
-def default_gather_mode(backend: Optional[str] = None) -> str:
-    """Pick the stage-1 x-gather flavor for this backend, measured not guessed.
-
-    One-shot microbenchmark (cached per process *per backend*) of the two
-    gather idioms at a representative stage-1 shape: ``jnp.take`` (native
-    gather ports) vs the one-hot matmul (MXU gather).  TPUs with few gather
-    ports tend to prefer the matmul; CPU/GPU interpret runs prefer ``take``.
-
-    The cache key is honest: ``backend=None`` normalizes to the process
-    default backend BEFORE caching (so ``default_gather_mode()`` and
-    ``default_gather_mode(jax.default_backend())`` share one entry), and the
-    microbench actually runs on the named backend's first device via
-    ``jax.default_device``.  A backend not attached to this process raises
-    ``RuntimeError`` from ``jax.devices`` rather than silently measuring the
-    default backend under the wrong cache key.
-    """
-    return _measured_gather_mode(backend or jax.default_backend())
+def default_interpret() -> bool:
+    """The one rule for running the Pallas kernels: compiled by Mosaic on a
+    TPU, through the Pallas interpreter on the CPU, and nowhere else."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the BS-CSR kernels run compiled on a TPU or interpreted on the CPU, "
+        f"not on {backend!r}"
+    )
 
 
-@functools.lru_cache(maxsize=None)
-def _measured_gather_mode(backend: str) -> str:
-    device = jax.devices(backend)[0]  # raises RuntimeError if unavailable
-    m, tb = 256, 512
-    rng = np.random.default_rng(0)
-    with jax.default_device(device):
-        x = jnp.asarray(rng.standard_normal(m), jnp.float32)
-        c = jnp.asarray(rng.integers(0, m, size=tb), jnp.int32)
-        ids = jnp.arange(m, dtype=jnp.int32)
-        take_fn = jax.jit(lambda x, c: jnp.take(x, c))
-        onehot_fn = jax.jit(
-            lambda x, c: jnp.dot(
-                (c[:, None] == ids[None, :]).astype(jnp.float32), x,
-                preferred_element_type=jnp.float32,
-            )
-        )
-
-        def measure(fn) -> float:
-            fn(x, c).block_until_ready()      # compile outside the timed loop
-            t0 = time.perf_counter()
-            for _ in range(30):
-                fn(x, c).block_until_ready()
-            return time.perf_counter() - t0
-
-        return "take" if measure(take_fn) <= measure(onehot_fn) else "onehot"
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``None`` -> :func:`default_interpret`; an explicit choice is kept."""
+    return default_interpret() if interpret is None else bool(interpret)
 
 
 def resolve_gather_mode(gather_mode: str) -> str:
-    """Map "auto" to the measured per-backend default; pass others through.
+    """Map "auto" to the gather the compiled kernel uses ("onehot").
 
-    Inside a jax trace wall-clock timing is meaningless (and ``.block_until_
-    ready`` unavailable), so "auto" falls back to "take" there instead of
-    poisoning the per-process cache.
+    "take" stays available as the interpret-only reference gather; the
+    kernel refuses it when ``interpret=False``.
     """
-    if gather_mode != "auto":
-        return gather_mode
-    try:
-        return default_gather_mode()
-    except AttributeError:  # called under tracing: no concrete timing possible
-        _measured_gather_mode.cache_clear()
-        return "take"
+    return "onehot" if gather_mode == "auto" else gather_mode
 
 
 def _kernel_streams(packed: PackedPartitions, stream_layout: Optional[str]):
@@ -913,18 +878,12 @@ def _grouped_local_topk(
     for g in packed.groups:
         common = dict(
             k=k, n_rows=packed.max_slots, packets_per_step=packets_per_step,
-            fmt_name=g.class_name, inner_loop=inner_loop,
-            stream_layout="fused", block_size=packed.block_size,
-            interpret=interpret,
+            fmt_name=g.class_name, gather_mode=gather_mode,
+            inner_loop=inner_loop, stream_layout="fused",
+            block_size=packed.block_size, interpret=interpret,
         )
-        if batched:
-            gv, gr = bscsr_topk_spmv_multiquery(
-                x, jnp.asarray(g.words), **common
-            )
-        else:
-            gv, gr = bscsr_topk_spmv(
-                x, jnp.asarray(g.words), gather_mode=gather_mode, **common
-            )
+        kernel = bscsr_topk_spmv_multiquery if batched else bscsr_topk_spmv
+        gv, gr = kernel(x, jnp.asarray(g.words), **common)
         cores = jnp.asarray(np.asarray(g.cores, np.int32))
         lv = lv.at[cores].set(gv)
         lr = lr.at[cores].set(gr)
@@ -937,12 +896,16 @@ def topk_spmv_blocked(
     big_k: int,
     k: int = 8,
     packets_per_step: int = 2,
-    gather_mode: str = "take",
+    gather_mode: str = "onehot",
     inner_loop: str = "linear",
     stream_layout: Optional[str] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Single-device multi-core approximate Top-K SpMV via the Pallas kernel."""
+    """Single-device multi-core approximate Top-K SpMV via the Pallas kernel.
+
+    ``interpret=None`` follows :func:`default_interpret`.
+    """
+    interpret = resolve_interpret(interpret)
     layout = stream_layout or packed.stream_layout
     if layout == "fused" and packed.groups is not None:
         lv, lr = _grouped_local_topk(
@@ -977,9 +940,10 @@ def topk_spmv_batched(
     big_k: int,
     k: int = 8,
     packets_per_step: int = 2,
+    gather_mode: str = "onehot",
     inner_loop: str = "linear",
     stream_layout: Optional[str] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Q queries in ONE pass over the stream via the multi-query kernel.
 
@@ -988,11 +952,13 @@ def topk_spmv_batched(
     """
     if xs.ndim != 2 or xs.shape[0] == 0:
         raise ValueError(f"xs must be a non-empty (Q, M) batch, got {xs.shape}")
+    interpret = resolve_interpret(interpret)
+    gather_mode = resolve_gather_mode(gather_mode)
     layout = stream_layout or packed.stream_layout
     if layout == "fused" and packed.groups is not None:
         lv, lr = _grouped_local_topk(
             jnp.asarray(xs, jnp.float32), packed, k=k,
-            packets_per_step=packets_per_step, gather_mode="take",
+            packets_per_step=packets_per_step, gather_mode=gather_mode,
             inner_loop=inner_loop, interpret=interpret, batched=True,
         )
         return finalize_candidates_batched(
@@ -1006,6 +972,7 @@ def topk_spmv_batched(
         n_rows=packed.max_slots,
         packets_per_step=packets_per_step,
         fmt_name=packed.value_format.name,
+        gather_mode=gather_mode,
         inner_loop=inner_loop,
         stream_layout=layout,
         block_size=packed.block_size,
@@ -1117,10 +1084,10 @@ def bscsr_spmv_blocked(
     y: Optional[jnp.ndarray] = None,
     n_out: Optional[int] = None,
     packets_per_step: int = 2,
-    gather_mode: str = "take",
+    gather_mode: str = "onehot",
     inner_loop: str = "linear",
     stream_layout: Optional[str] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """``y = alpha * A @ x + beta * y`` via the accumulate-mode Pallas kernel.
 
@@ -1132,6 +1099,7 @@ def bscsr_spmv_blocked(
     """
     if n_out is None:
         n_out = int(y.shape[0]) if y is not None else packed.n_rows_logical
+    interpret = resolve_interpret(interpret)
     layout = stream_layout or packed.stream_layout
     xd = jnp.asarray(x, jnp.float32)
     if layout == "fused" and packed.groups is not None:

@@ -148,7 +148,13 @@ def bscsr_slot_sums_stacked(
 def csr_topk_numpy(indptr, indices, data, x, big_k: int):
     """Numpy CSR Top-K — the host-side 'sparse_dot_topn' style baseline."""
     prods = data * x[indices]
-    scores = np.zeros(len(indptr) - 1, dtype=np.float32)
-    np.add.at(scores, np.repeat(np.arange(len(indptr) - 1), np.diff(indptr)), prods)
-    order = np.lexsort((np.arange(len(scores)), -scores))[:big_k]
+    n = len(indptr) - 1
+    scores = np.zeros(n, dtype=np.float32)
+    filled = np.diff(indptr) > 0       # empty rows score 0
+    if filled.any():
+        scores[filled] = np.add.reduceat(prods, indptr[:-1][filled])
+    cand = np.arange(n)
+    if big_k < n:                      # ties at the K-th value stay in
+        cand = np.nonzero(scores >= np.partition(scores, n - big_k)[n - big_k])[0]
+    order = cand[np.lexsort((cand, -scores[cand]))][:big_k]
     return scores[order], order.astype(np.int32)

@@ -221,7 +221,6 @@ def run_topk_service_cell(multi_pod: bool) -> dict:
             core.TopKSpMVConfig(
                 big_k=CONFIG.big_k, k=CONFIG.k, num_partitions=n_parts,
                 block_size=CONFIG.block_size, value_format="F32",
-                interpret=True,
             ),
         )
         with mesh:

@@ -132,7 +132,7 @@ class StreamingSimilarityService:
         guardrails: Optional[ServiceGuardrails] = None,
         store: Optional[DurableIndexStore] = None,
         frontend: Optional[FrontendConfig] = None,
-        use_kernel: bool = False,
+        use_kernel: bool = True,
     ):
         self.index = index
         self.policy = policy or CompactionPolicy()
@@ -203,9 +203,12 @@ class StreamingSimilarityService:
         self.checkpoints += 1
 
     def search(
-        self, xs: np.ndarray, use_kernel: bool = False
+        self, xs: np.ndarray, use_kernel: Optional[bool] = None
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Answer a (Q, M) query batch from the current snapshot.
+
+        Runs the Pallas kernel unless ``use_kernel`` (default: the
+        service's own ``use_kernel``) asks for the reference oracle.
 
         Guardrails (when enabled): sheds load once ``max_in_flight`` calls
         are executing, retries transient dispatch failures with exponential
@@ -223,6 +226,8 @@ class StreamingSimilarityService:
             self._in_flight += 1
         try:
             xs = np.atleast_2d(np.asarray(xs, np.float32))
+            if use_kernel is None:
+                use_kernel = self.use_kernel
             with Watchdog(g.deadline_s, raise_on_timeout=True) as wd:
                 out = self._dispatch_with_retry(xs, use_kernel, wd)
             self.queries_served += xs.shape[0]

@@ -92,7 +92,7 @@ class ApproxTopKHead:
         )
 
     def topk_logits(
-        self, hidden: np.ndarray, use_kernel: bool = False
+        self, hidden: np.ndarray, use_kernel: bool = True
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Approximate top-K (logits, token ids) for one hidden state (D,)."""
         if self._sharded:

@@ -249,17 +249,20 @@ class TestGatherHardening:
 
 class TestAutoGatherMode:
     def test_resolves_to_supported_mode(self):
-        mode = ops.default_gather_mode()
-        assert mode in ("take", "onehot")
-        assert ops.resolve_gather_mode("auto") == mode
+        assert ops.resolve_gather_mode("auto") == "onehot"
         assert ops.resolve_gather_mode("onehot") == "onehot"
+        assert ops.resolve_gather_mode("take") == "take"
+        csr, x = make_problem(n_rows=40, n_cols=64, mean_nnz=5, seed=24)
+        packed = ops.pack_partitions(csr, 2, 32, "F32", stream_layout="fused")
+        with pytest.raises(ValueError, match="interpret-only"):
+            ops.topk_spmv_blocked(jnp.asarray(x), packed, 8,
+                                  gather_mode="take", interpret=False)
 
     def test_auto_config_end_to_end(self):
         csr, x = make_problem(n_rows=150, seed=23)
         idx = core.build_index(csr, TopKSpMVConfig(
             big_k=10, k=8, num_partitions=2, block_size=64, gather_mode="auto"))
         a = core.topk_spmv(idx, jnp.asarray(x))
-        resolved = ops.default_gather_mode()
         b = ops.topk_spmv_blocked(jnp.asarray(x), idx.packed, 10,
-                                  gather_mode=resolved)
+                                  gather_mode="onehot")
         assert_bit_identical(a, b)

@@ -144,12 +144,12 @@ class TestMultiQueryParity:
                 jnp.asarray(packed.flags))
         mv, mr = bscsr_topk_spmv_multiquery(
             jnp.asarray(xs), *args, k=8, n_rows=max_rows, fmt_name=fmt,
-            inner_loop=inner_loop,
+            inner_loop=inner_loop, interpret=True,
         )
         for q in range(xs.shape[0]):
             sv, sr = bscsr_topk_spmv(
                 jnp.asarray(xs[q]), *args, k=8, n_rows=max_rows, fmt_name=fmt,
-                inner_loop=inner_loop,
+                inner_loop=inner_loop, interpret=True,
             )
             np.testing.assert_allclose(np.asarray(mv[:, q]), np.asarray(sv),
                                        rtol=1e-6, atol=1e-6)

@@ -77,3 +77,46 @@ def test_query_batch_kernel_matches_reference(service, rng):
     rv, rr = service.query_batch(xs, use_kernel=False)
     np.testing.assert_allclose(kv, rv, rtol=1e-4, atol=1e-4)
     np.testing.assert_array_equal(kr, rr)
+
+
+def test_served_defaults_dispatch_the_kernel(service, rng):
+    """query_batch, the streaming service and the top-k head all run the
+    Pallas kernel unless the reference oracle is asked for by name."""
+    from repro.serve import StreamingSimilarityService
+    from repro.serve.topk_head import ApproxTopKHead, TopKHeadConfig
+
+    xs = rng.standard_normal((2, 256)).astype(np.float32)
+    kv, kr = service.query_batch(xs)
+    np.testing.assert_array_equal(kr, service.query_batch(xs, use_kernel=True)[1])
+    info = service.dispatch_info()
+    assert "kernel" in info["paths"] and info["interpret"] is True
+    assert info["gather_mode"] == "onehot"
+    svc = StreamingSimilarityService(service)
+    assert svc.use_kernel is True
+    np.testing.assert_array_equal(svc.search(xs)[1], kr)
+    head = ApproxTopKHead(
+        rng.standard_normal((300, 32)).astype(np.float32),
+        TopKHeadConfig(big_k=8, k=8, num_partitions=2, nnz_per_row=8, block_size=64),
+    )
+    h = rng.standard_normal(32).astype(np.float32)
+    np.testing.assert_array_equal(head.topk_logits(h)[1],
+                                  head.topk_logits(h, use_kernel=True)[1])
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False)])
+def test_interpret_resolves_from_the_backend(monkeypatch, backend, want):
+    """interpret=None: interpreted on the CPU, compiled on a TPU."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    assert ops.default_interpret() is want
+    assert core.TopKSpMVConfig().resolve_interpret() is want
+    assert core.TopKSpMVConfig(interpret=not want).resolve_interpret() is (not want)
+
+
+def test_interpret_refuses_other_backends(monkeypatch):
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="not on 'gpu'"):
+        core.TopKSpMVConfig().resolve_interpret()

@@ -166,7 +166,7 @@ class TestMultiQuery:
         lv, lr = bscsr_topk_spmv_multiquery(
             jnp.asarray(xs), jnp.asarray(packed.vals), jnp.asarray(packed.cols),
             jnp.asarray(packed.flags), k=8, n_rows=max_rows,
-            fmt_name=fmt,
+            fmt_name=fmt, interpret=True,
         )
         for q in range(xs.shape[0]):
             fv, fr = ops.finalize_candidates(
