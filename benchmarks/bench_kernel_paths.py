@@ -1,4 +1,4 @@
-"""Kernel-path perf trajectory: inner loops x layouts x batching x dispatch.
+"""Kernel-path perf trajectory: inner loops x layouts x batching x formats.
 
 Four sweeps at the paper's design point (B = 256, T = 2):
 
@@ -12,13 +12,12 @@ Four sweeps at the paper's design point (B = 256, T = 2):
     the per-backend mode the one-shot microbenchmark resolves "auto" to.
   * batching: single vs multi-query at Q in {1, 8, 64} on both layouts — the
     batched call streams the matrix ONCE for all Q queries.
-  * dispatch: the legacy per-call path (re-``jnp.asarray`` every stream +
-    finalize array per query) vs the device-resident executor (streams
-    pinned once per snapshot, kernel+finalize in one cached jit).  Reports
-    cold (pin + trace) vs steady-state executor latency, end-to-end call
-    time, and the isolated per-query dispatch overhead: host->device prep of
-    the legacy path vs the executor's cache-hit ``prepare`` — the ratio is
-    the acceptance headline (target >= 2x).
+  * mixed precision: per-partition value formats chosen for a recall
+    target on a hot/cold collection, bit-identical to the f32 twins on every
+    inner loop, with recall@8 measured through the kernel.
+
+The executor's per-query dispatch time is read on the chip, from the
+program's ``repro.executor.dispatch`` spans (``bench/spans.py``).
 
 Numbers are host-side interpret-mode timings (the correctness harness, not
 TPU silicon), but the work ratio between paths is real.  Results merge into
@@ -28,8 +27,6 @@ loops on both layouts so no perf path can rot unexercised, and skips the
 json write.
 """
 from __future__ import annotations
-
-import time
 
 import jax
 import jax.numpy as jnp
@@ -41,7 +38,6 @@ try:
 except ImportError:  # direct script run: benchmarks/ itself is sys.path[0]
     from bench_io import BENCH_JSON, merge_into_bench_json, time_paired
 from repro.core import bscsr
-from repro.kernels import executor as executor_lib
 from repro.kernels import ops
 from repro.kernels.bscsr_topk_spmv import INNER_LOOPS
 
@@ -175,64 +171,7 @@ def run(verbose: bool = True, n_rows: int = 8192, n_cols: int = 256,
                       f"speedup {t_single[layout]*q/t_batch:5.1f}x  "
                       f"{nnz*q/t_batch/1e9:.4f} GNNZ/s")
 
-    # --- sweep 4: dispatch path (per-call upload vs device-resident executor) ---
-    pk = packed["fused"]
-    xd = jnp.asarray(x)
-    # same gather kernel on both arms: the comparison must isolate dispatch
-    ex = executor_lib.QueryExecutor(big_k=BIG_K, k=K, packets_per_step=T_STEP,
-                                    gather_mode=auto_mode)
-    t0 = time.perf_counter()
-    ex.query(xd, pk)[0].block_until_ready()      # pin + trace + first run
-    cold_s = time.perf_counter() - t0
-    ts = time_paired({
-        "legacy": lambda: ops.topk_spmv_blocked(
-            xd, pk, BIG_K, k=K, packets_per_step=T_STEP,
-            gather_mode=auto_mode,
-        )[0].block_until_ready(),
-        "executor": lambda: ex.query(xd, pk)[0].block_until_ready(),
-    }, repeats)
-    total = {k: float(np.median(v)) for k, v in ts.items()}
-
-    def legacy_prep():
-        # exactly what the per-call path re-does before every kernel launch
-        _, streams = ops._kernel_streams(pk, None)
-        arrs = [s for s in streams if s is not None]
-        arrs += [v for v in ops._finalize_kwargs(pk).values()
-                 if hasattr(v, "block_until_ready")]
-        for a in arrs:
-            a.block_until_ready()
-
-    prep = time_paired({
-        "legacy": legacy_prep,
-        "executor": lambda: ex.prepare(pk),      # two dict hits, steady state
-    }, max(repeats, 20))
-    prep_us = {k: float(np.median(v)) * 1e6 for k, v in prep.items()}
-    overhead_speedup = prep_us["legacy"] / max(prep_us["executor"], 1e-3)
-    dispatch = {
-        "cold_us": cold_s * 1e6,
-        "steady_us": total["executor"] * 1e6,
-        "legacy_us": total["legacy"] * 1e6,
-        "legacy_prep_us_per_call": prep_us["legacy"],
-        "executor_prep_us_per_call": prep_us["executor"],
-        "stream_bytes_uploaded_per_call_legacy": pk.fused_words().nbytes,
-        "dispatch_overhead_speedup": overhead_speedup,
-    }
-    for path in ("legacy", "executor"):
-        results.append({
-            "sweep": "dispatch", "fmt": "F32", "inner_loop": "linear",
-            "layout": "fused", "q": 1, "dispatch": path,
-            "us_per_call": total[path] * 1e6,
-            "prep_us_per_call": prep_us[path],
-            "gnnz_per_s": nnz / total[path] / 1e9,
-        })
-    if verbose:
-        print(f"dispatch   legacy  {total['legacy']*1e3:8.2f} ms/call "
-              f"(prep {prep_us['legacy']:8.1f} us)")
-        print(f"dispatch   executor{total['executor']*1e3:8.2f} ms/call "
-              f"(prep {prep_us['executor']:8.1f} us, cold {cold_s*1e3:.0f} ms)"
-              f"  overhead speedup {overhead_speedup:.1f}x")
-
-    # --- sweep 5: per-partition mixed-precision streams (recall-targeted) ---
+    # --- sweep 4: per-partition mixed-precision streams (recall-targeted) ---
     # Hot/cold collection: a few partitions carry full-magnitude scores, the
     # rest are scaled down (cold shards never contend for the global top-k) —
     # the regime where per-partition formats beat any single uniform format.
@@ -363,17 +302,17 @@ def run(verbose: bool = True, n_rows: int = 8192, n_cols: int = 256,
 
     by = {
         (r["sweep"], r["fmt"], r["inner_loop"], r["layout"],
-         r.get("gather_mode"), r.get("dispatch"), r["q"]): r
+         r.get("gather_mode"), r["q"]): r
         for r in results
     }
 
-    def us(sweep, fmt, loop, layout, gather=None, dispatch=None, q=1):
-        return by[(sweep, fmt, loop, layout, gather, dispatch, q)]["us_per_call"]
+    def us(sweep, fmt, loop, layout, gather=None, q=1):
+        return by[(sweep, fmt, loop, layout, gather, q)]["us_per_call"]
 
     speedup_inner = (us("inner_loop", "F32", "legacy", "split")
                      / us("inner_loop", "F32", "linear", "split"))
     qmax = qs[-1]
-    speedup_batch = by[("batching", "F32", "linear", "fused", None, None, qmax)][
+    speedup_batch = by[("batching", "F32", "linear", "fused", None, qmax)][
         "speedup_vs_sequential"]
     # Headline layout comparison at the deployment format (configs/topk_spmv
     # and the serving head ship BF16); the full per-format table is in
@@ -395,7 +334,6 @@ def run(verbose: bool = True, n_rows: int = 8192, n_cols: int = 256,
         "fused_vs_split_by_format": fused_ratio,
         "speedup_fused_vs_split_bf16": speedup_fused,
         f"speedup_batched_q{qmax}_vs_sequential": speedup_batch,
-        "executor_dispatch": dispatch,
         "mixed_precision": mixed_precision,
     }
     if not smoke:  # CI smoke must not clobber the tracked repo-root numbers
@@ -404,8 +342,7 @@ def run(verbose: bool = True, n_rows: int = 8192, n_cols: int = 256,
         ratios = " ".join(f"{f}={r:.2f}x" for f, r in fused_ratio.items())
         print(f"linear vs legacy (F32, split): {speedup_inner:.1f}x   "
               f"fused vs split: {ratios}   "
-              f"batched Q={qmax} vs sequential: {speedup_batch:.1f}x   "
-              f"dispatch overhead: {overhead_speedup:.1f}x")
+              f"batched Q={qmax} vs sequential: {speedup_batch:.1f}x")
         if not smoke:
             print(f"wrote {BENCH_JSON}")
     return {
@@ -413,8 +350,7 @@ def run(verbose: bool = True, n_rows: int = 8192, n_cols: int = 256,
         "us_per_call": us("inner_loop", "F32", "linear", "fused"),
         "derived": (f"linear_vs_legacy={speedup_inner:.1f}x "
                     f"fused_vs_split_bf16={speedup_fused:.2f}x "
-                    f"batchQ{qmax}_vs_seq={speedup_batch:.1f}x "
-                    f"dispatch_overhead={overhead_speedup:.1f}x"),
+                    f"batchQ{qmax}_vs_seq={speedup_batch:.1f}x"),
     }
 
 

@@ -2,12 +2,10 @@
 
 Prints ``name,us_per_call,derived`` CSV (plus verbose detail per benchmark).
 ``--smoke`` runs the CI perf-path smoke instead: tiny shapes through the
-kernel-path sweep (all inner loops, both stream layouts, both dispatch
-paths), the serve-while-ingest churn axis (both signature modes with
-retrace counting), the 8-simulated-device sharded serving plane
-(bit-identity + transfer-guard/retrace assertions), and the open-loop
-arrival sweep (micro-batching frontend beats fixed-Q=1 at equal-or-better
-p99, zero retraces across drifting Q), and the iterative graph workloads
+kernel-path sweep (all inner loops, both stream layouts), the
+serve-while-ingest churn axis (both signature modes with retrace
+counting), the 8-simulated-device sharded serving plane (bit-identity +
+transfer-guard/retrace assertions), and the iterative graph workloads
 (accumulate-mode PPR/eigen: parity, zero-transfer/zero-retrace loops,
 bit-identical incremental re-solves) — no json writes.
 """
@@ -23,7 +21,6 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
 def main(smoke: bool = False) -> None:
     from benchmarks import (
-        bench_arrival_sweep,
         bench_graph_workloads,
         bench_kernel_paths,
         bench_recovery,
@@ -39,15 +36,13 @@ def main(smoke: bool = False) -> None:
 
     if smoke:
         mods = [bench_kernel_paths, bench_streaming_updates,
-                bench_sharded_serving, bench_recovery, bench_arrival_sweep,
-                bench_graph_workloads]
+                bench_sharded_serving, bench_recovery, bench_graph_workloads]
         kwargs, banner = {"smoke": True}, " [smoke]"
     else:
         mods = [table1_precision, table2_designs, fig5_throughput,
                 fig6_roofline, fig7_accuracy, kernel_validation,
                 bench_kernel_paths, bench_streaming_updates,
-                bench_sharded_serving, bench_recovery, bench_arrival_sweep,
-                bench_graph_workloads]
+                bench_sharded_serving, bench_recovery, bench_graph_workloads]
         kwargs, banner = {}, ""
     rows = []
     for mod in mods:

@@ -31,6 +31,7 @@ import numpy as np
 from repro.core import bscsr as bscsr_lib
 from repro.core import topk_spmv as topk_lib
 from repro.core import sharded as sharded_lib
+from repro.utils.tracing import span
 
 
 @dataclasses.dataclass
@@ -209,16 +210,21 @@ class SparseEmbeddingIndex:
         ``query`` (Q=1), ``query_batch`` and the frontend's coalesced
         passes all land here — one place that derives the executor from
         the config and routes sharded vs single-device, so the executor's
-        cache/bucket counters count every path the same way.
+        cache/bucket counters count every path the same way.  The query
+        block's upload and the answers' fetch are the ``index.upload`` and
+        ``index.fetch`` spans; the fetch waits for the device.
         """
-        xs = jnp.asarray(xs, jnp.float32)
+        q = int(np.shape(xs)[0])
+        with span("index.upload", q=q):
+            xs = jnp.asarray(xs, jnp.float32)
         if self.is_sharded:
             v, r = self.index.query_batched(xs, use_kernel=use_kernel)
         else:
             v, r = topk_lib.topk_spmv_batched(
                 self.index, xs, use_kernel=use_kernel
             )
-        return np.asarray(v), np.asarray(r)
+        with span("index.fetch", q=q):
+            return np.asarray(v), np.asarray(r)
 
     def query_exact(self, x: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """Exact Top-K over the *live* rows — ground truth for accuracy checks.

@@ -28,6 +28,7 @@ from repro.core.quantization import F32, FORMATS, width_class_of
 from repro.kernels import executor as executor_lib
 from repro.kernels import ops as kernel_ops
 from repro.kernels import ref as ref_lib
+from repro.utils.tracing import span
 
 @dataclasses.dataclass(frozen=True)
 class TopKSpMVConfig:
@@ -167,12 +168,19 @@ class MutableTopKSpMVIndex:
     """
 
     def __init__(self, csr: bscsr_lib.CSRMatrix, config: TopKSpMVConfig):
+        with span("index.build", kind="init", rows=int(csr.shape[0]),
+                  nnz=int(csr.nnz)):
+            self._build(csr, config)
+
+    def _build(self, csr: bscsr_lib.CSRMatrix, config: TopKSpMVConfig) -> None:
+        """The body of ``__init__``, one child span per build stage."""
         self.config = config
         self._n_cols = csr.shape[1]
         self._fmt = FORMATS[config.value_format]
         c = config.resolve_partitions(csr.shape[0])
         self._plan = partition_lib.PartitionPlan.build(csr.shape[0], c)
-        parts = partition_lib.partition_csr(csr, self._plan)
+        with span("index.partition", partitions=c):
+            parts = partition_lib.partition_csr(csr, self._plan)
         # Mixed-precision plane (config.recall_target): three aligned stream
         # copies per partition — ``_exact`` (F32, the structural + numeric
         # source of truth), ``_native`` (the partition's assigned format,
@@ -195,7 +203,7 @@ class MutableTopKSpMVIndex:
             self._calib = calib
             self._fmt = F32  # the split twin plane is uniformly f32
             self._exact = [
-                bscsr_lib.encode_bscsr(p, config.block_size, F32) for p in parts
+                self._encode(ci, p, F32) for ci, p in enumerate(parts)
             ]
             self._native = [
                 bscsr_lib.requantize_stream(e, FORMATS[f])
@@ -206,27 +214,27 @@ class MutableTopKSpMVIndex:
             ]
         else:
             self._streams = [
-                bscsr_lib.encode_bscsr(p, config.block_size, self._fmt)
-                for p in parts
+                self._encode(ci, p, self._fmt) for ci, p in enumerate(parts)
             ]
         self._base_packets = max(e.num_packets for e in self._streams)
-        self._slots = [
-            list(range(start, start + size))
-            for start, size in zip(
-                self._plan.row_starts, self._plan.rows_per_partition
-            )
-        ]
-        self._loc = {
-            gid: (ci, si)
-            for ci, slots in enumerate(self._slots)
-            for si, gid in enumerate(slots)
-        }
-        cols_split = np.split(csr.indices, csr.indptr[1:-1])
-        data_split = np.split(csr.data, csr.indptr[1:-1])
-        self._rows = {
-            gid: (cols_split[gid].astype(np.int32), data_split[gid])
-            for gid in range(csr.shape[0])
-        }
+        with span("index.row_maps", rows=int(csr.shape[0])):
+            self._slots = [
+                list(range(start, start + size))
+                for start, size in zip(
+                    self._plan.row_starts, self._plan.rows_per_partition
+                )
+            ]
+            self._loc = {
+                gid: (ci, si)
+                for ci, slots in enumerate(self._slots)
+                for si, gid in enumerate(slots)
+            }
+            cols_split = np.split(csr.indices, csr.indptr[1:-1])
+            data_split = np.split(csr.data, csr.indptr[1:-1])
+            self._rows = {
+                gid: (cols_split[gid].astype(np.int32), data_split[gid])
+                for gid in range(csr.shape[0])
+            }
         self._deleted = bscsr_lib.TombstoneBitmap.empty(csr.shape[0])
         self._next_gid = csr.shape[0]
         self._live_nnz = csr.nnz
@@ -247,6 +255,11 @@ class MutableTopKSpMVIndex:
         self.total_group_copied = 0         # COW width-class group stacks
         self.last_compact_parallel = False
         self._refresh()
+
+    def _encode(self, ci: int, part: bscsr_lib.CSRMatrix, fmt):
+        """BS-CSR encode of partition ``ci`` (an ``index.encode`` span)."""
+        with span("index.encode", partition=ci, nnz=int(part.nnz)):
+            return bscsr_lib.encode_bscsr(part, self.config.block_size, fmt)
 
     def _reset_padded_cache(self) -> None:
         """Invalidate the per-partition padded-stream (+ fused words) cache."""
@@ -309,6 +322,13 @@ class MutableTopKSpMVIndex:
         compiled query fns are reused with ZERO retraces until a bucket
         doubles (docs/ARCHITECTURE.md, "where does a query retrace?").
         """
+        with span("index.refresh") as s:
+            self._swap_snapshot(preserve_caps)
+            s.set_metadata(version=self._version,
+                           partitions_copied=self.last_refresh_copied)
+
+    def _swap_snapshot(self, preserve_caps: bool) -> None:
+        """The body of :meth:`_refresh`."""
         hetero = self._part_fmts is not None
         # Mixed-precision snapshots never carry uniform fused words — their
         # fused dispatch plane is the per-width-class tagged groups below.
@@ -771,12 +791,19 @@ class MutableTopKSpMVIndex:
         afterwards via the global tombstone bitmap.
         """
         csr, gids = self.live_csr()
+        with span("index.build", kind="compact", rows=int(csr.shape[0]),
+                  nnz=int(csr.nnz)):
+            self._compact(csr, gids)
+
+    def _compact(self, csr: bscsr_lib.CSRMatrix, gids: np.ndarray) -> None:
+        """The body of :meth:`compact` over the live rows ``csr``/``gids``."""
         c = max(1, self.config.resolve_partitions(max(csr.shape[0], 1)))
         plan = partition_lib.PartitionPlan.build(csr.shape[0], c)
-        parts = partition_lib.partition_csr(csr, plan)
+        with span("index.partition", partitions=c):
+            parts = partition_lib.partition_csr(csr, plan)
 
-        def encode(p):
-            return bscsr_lib.encode_bscsr(p, self.config.block_size, self._fmt)
+        def encode(ci):
+            return self._encode(ci, parts[ci], self._fmt)
 
         # self._packed still serves while partitions re-encode.
         parallel = (
@@ -787,9 +814,9 @@ class MutableTopKSpMVIndex:
         if parallel:
             workers = min(len(parts), os.cpu_count() or 1)
             with ThreadPoolExecutor(max_workers=workers) as pool:
-                streams = list(pool.map(encode, parts))
+                streams = list(pool.map(encode, range(len(parts))))
         else:
-            streams = [encode(p) for p in parts]
+            streams = [encode(ci) for ci in range(len(parts))]
         self.last_compact_parallel = parallel
         new_fmts = new_calib = new_exact = new_native = None
         if self._part_fmts is not None:
@@ -821,15 +848,16 @@ class MutableTopKSpMVIndex:
         self._base_packets = max(e.num_packets for e in streams)
         self._plan = plan
         self._reset_padded_cache()
-        self._slots = [
-            [int(g) for g in gids[start : start + size]]
-            for start, size in zip(plan.row_starts, plan.rows_per_partition)
-        ]
-        self._loc = {
-            gid: (ci, si)
-            for ci, slots in enumerate(self._slots)
-            for si, gid in enumerate(slots)
-        }
+        with span("index.row_maps", rows=int(csr.shape[0])):
+            self._slots = [
+                [int(g) for g in gids[start : start + size]]
+                for start, size in zip(plan.row_starts, plan.rows_per_partition)
+            ]
+            self._loc = {
+                gid: (ci, si)
+                for ci, slots in enumerate(self._slots)
+                for si, gid in enumerate(slots)
+            }
         self._delta_nnz = 0
         self._dead_nnz = 0
         self._tombstone_slots = 0

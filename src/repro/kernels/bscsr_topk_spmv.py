@@ -567,6 +567,7 @@ def bscsr_topk_spmv_multiquery(
             pltpu.VMEM((rows, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="bscsr_topk_spmv_multiquery",
     )(x, *streams)
     return v[:, :nq], r[:, :nq]
 
@@ -653,4 +654,5 @@ def bscsr_spmv(
             pltpu.VMEM((x.shape[0], 1), jnp.float32),
         ],
         interpret=interpret,
+        name="bscsr_spmv",
     )(x, *streams)[:, 0]

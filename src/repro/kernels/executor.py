@@ -73,6 +73,7 @@ from repro.kernels.bscsr_topk_spmv import (
     bscsr_topk_spmv,
     bscsr_topk_spmv_multiquery,
 )
+from repro.utils.tracing import span
 
 # (snapshot uid, stream layout) -> DeviceSnapshot; entries evicted when the
 # host PackedPartitions is garbage collected.
@@ -242,10 +243,28 @@ def device_snapshot(
     key = (packed.uid, layout, row_map_key, device)
     snap = _DEVICE_CACHE.get(key)
     if snap is None:
-        snap = DeviceSnapshot(packed, layout, row_map=row_map, device=device)
+        with span("executor.pin") as s:
+            snap = DeviceSnapshot(packed, layout, row_map=row_map, device=device)
+            s.set_metadata(bytes=sum(int(a.nbytes) for a in snap.args))
         _DEVICE_CACHE[key] = snap
         weakref.finalize(packed, _DEVICE_CACHE.pop, key, None)
     return snap
+
+
+def _compile_on_first_call(fn: Callable, **ids) -> Callable:
+    """``fn`` whose first call (trace + compile) runs in ``executor.compile``."""
+    compiled = False
+
+    def call(*args):
+        nonlocal compiled
+        if compiled:
+            return fn(*args)
+        with span("executor.compile", **ids):
+            out = fn(*args)
+        compiled = True
+        return out
+
+    return call
 
 
 def _q_bucket(q: int) -> int:
@@ -365,17 +384,20 @@ class QueryExecutor:
         fn = self._fns.get(key)
         if fn is None:
             live = self._evict_stale()    # misses mark a shifting working set
-            fn = self._build(path, q, snap)
-            self._fns[key] = fn
-            self.fn_builds += 1
             prev = self._last_sig.get((path, q))
             # A retrace is churn: this pair's previous signature is DEAD
             # (its snapshots were replaced and collected).  A build while
             # the previous signature still serves live snapshots is just a
             # first touch for another collection sharing this interned
             # executor — not a churn signal.
-            if prev is not None and prev != snap.signature and prev not in live:
-                self.retraces += 1
+            retrace = (prev is not None and prev != snap.signature
+                       and prev not in live)
+            ids = dict(path=path, q=q if isinstance(q, int) else str(q))
+            with span("executor.build", retrace=int(retrace), **ids):
+                fn = _compile_on_first_call(self._build(path, q, snap), **ids)
+            self._fns[key] = fn
+            self.fn_builds += 1
+            self.retraces += retrace
             self._last_sig[(path, q)] = snap.signature
         return fn, snap
 
@@ -436,21 +458,22 @@ class QueryExecutor:
             )
         q = xs.shape[0]
         bucket = _q_bucket(q) if self.q_bucketing else q
-        builds_before = self.fn_builds
-        fn, snap = self.prepare(
-            packed, bucket, path, stream_layout,
-            row_map=row_map, row_map_key=row_map_key, device=device,
-        )
-        if self.fn_builds == builds_before:  # reused a compiled fn
+        with span("executor.dispatch", q=q, bucket=bucket):
+            builds_before = self.fn_builds
+            fn, snap = self.prepare(
+                packed, bucket, path, stream_layout,
+                row_map=row_map, row_map_key=row_map_key, device=device,
+            )
+            if self.fn_builds == builds_before:  # reused a compiled fn
+                if bucket != q:
+                    self.q_bucket_hits += 1      # padded into a shared bucket
+                else:
+                    self.q_exact_hits += 1
+            self.dispatches += 1
             if bucket != q:
-                self.q_bucket_hits += 1      # padded into a shared bucket
-            else:
-                self.q_exact_hits += 1
-        self.dispatches += 1
-        if bucket != q:
-            xs = _query_padder(bucket - q)(xs)
-        vals, rows = fn(xs, *snap.call_args(n_rows))
-        return _query_unpadder(q)(vals, rows) if bucket != q else (vals, rows)
+                xs = _query_padder(bucket - q)(xs)
+            vals, rows = fn(xs, *snap.call_args(n_rows))
+            return _query_unpadder(q)(vals, rows) if bucket != q else (vals, rows)
 
     def spmv(
         self,

@@ -13,8 +13,9 @@ Three cooperating pieces:
 
 * :class:`IntensityModel` — an online arrival/service model.  Arrival rate
   λ is an EWMA over inter-arrival gaps; per-Q-bucket service time s(B) is
-  an EWMA per power-of-two batch bucket (optionally seeded from the
-  Q-bucket bench numbers in ``BENCH_topk_spmv.json``).  The adaptive
+  an EWMA per power-of-two batch bucket (optionally seeded with measured
+  pass times, e.g. the ``frontend.flush`` span durations per bucket of a
+  traced run).  The adaptive
   target batch is the smallest bucket B with ``B >= λ * s(B)`` — the batch
   the queue refills during one kernel pass, i.e. the operating point where
   the pipeline neither idles nor grows an unbounded backlog.
@@ -34,9 +35,9 @@ scheduler is pure policy — no kernel or executor signature changes — and
 ``cache_info()``'s ``q_bucket_hits``/``q_exact_hits`` counters let tests
 assert exactly that.  ``StreamingSimilarityService(frontend=...)`` wires
 this frontend over the guardrailed dispatch path (deadlines measured from
-*enqueue* so queue wait counts against them); the open-loop Poisson sweep
-in ``benchmarks/bench_arrival_sweep.py`` records the resulting
-p50/p99-vs-QPS frontier against fixed-Q dispatch.
+*enqueue* so queue wait counts against them).  Each pass is a
+``frontend.flush`` span (``repro.utils.tracing``) carrying its Q, flush
+reason and the queue waits of its requests (docs/SERVING.md §"Spans").
 """
 from __future__ import annotations
 
@@ -48,6 +49,8 @@ from concurrent.futures import Future
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
+
+from repro.utils.tracing import span
 
 
 class QueueFullError(RuntimeError):
@@ -362,23 +365,27 @@ class RequestFrontend:
         xs = np.stack([r.x for r in batch]).astype(np.float32)
         enq = [r.enqueue_t for r in batch]
         t0 = time.monotonic()
-        try:
-            results = self.dispatch(xs, enq)
-        except Exception as e:
-            for r in batch:
-                if not r.future.cancelled():
-                    r.future.set_exception(e)
-            return
-        finally:
-            self.model.observe_service(q, time.monotonic() - t0)
-            self.completed += q
-        for r, res in zip(batch, results):
-            if r.future.cancelled():
-                continue
-            if isinstance(res, BaseException):
-                r.future.set_exception(res)
-            else:
-                r.future.set_result(res)
+        waits_us = [int((t0 - t) * 1e6) for t in enq]
+        with span("frontend.flush", **{"pass": self.flushes}, q=q,
+                  reason=reason, wait_sum_us=sum(waits_us),
+                  wait_max_us=max(waits_us)):
+            try:
+                results = self.dispatch(xs, enq)
+            except Exception as e:
+                for r in batch:
+                    if not r.future.cancelled():
+                        r.future.set_exception(e)
+                return
+            finally:
+                self.model.observe_service(q, time.monotonic() - t0)
+                self.completed += q
+            for r, res in zip(batch, results):
+                if r.future.cancelled():
+                    continue
+                if isinstance(res, BaseException):
+                    r.future.set_exception(res)
+                else:
+                    r.future.set_result(res)
 
     # -- lifecycle & introspection -------------------------------------------
 

@@ -50,6 +50,7 @@ from repro.core import bscsr as bscsr_lib
 from repro.core.persistence import DurableIndexStore
 from repro.core.similarity import SimilaritySearchStats, SparseEmbeddingIndex
 from repro.serve.frontend import FrontendConfig, RequestFrontend
+from repro.utils.tracing import span
 from repro.utils.watchdog import DeadlineExceeded, Watchdog
 
 
@@ -228,7 +229,8 @@ class StreamingSimilarityService:
             xs = np.atleast_2d(np.asarray(xs, np.float32))
             if use_kernel is None:
                 use_kernel = self.use_kernel
-            with Watchdog(g.deadline_s, raise_on_timeout=True) as wd:
+            with span("service.search", q=xs.shape[0]), \
+                    Watchdog(g.deadline_s, raise_on_timeout=True) as wd:
                 out = self._dispatch_with_retry(xs, use_kernel, wd)
             self.queries_served += xs.shape[0]
             self._note_degraded()
@@ -369,17 +371,20 @@ class StreamingSimilarityService:
         With a store attached the batch is write-ahead logged (as the
         sparsified rows the index will actually encode) BEFORE it applies,
         so a crash between log and apply replays to the identical state.
+        The call is the ``service.ingest`` span; the snapshot refresh it
+        triggers is its ``index.refresh`` child.
         """
-        if self.store is not None:
-            rows = self._sparse_rows(embeddings)
-            if ids is None:
-                self.store.log_add(rows)
-            else:
-                self.store.log_replace(list(ids), rows)
-        out = self.index.upsert(embeddings, ids=ids)
-        self.rows_ingested += len(out)
-        self._maybe_compact()
-        return out
+        with span("service.ingest", rows=len(np.atleast_2d(embeddings))):
+            if self.store is not None:
+                rows = self._sparse_rows(embeddings)
+                if ids is None:
+                    self.store.log_add(rows)
+                else:
+                    self.store.log_replace(list(ids), rows)
+            out = self.index.upsert(embeddings, ids=ids)
+            self.rows_ingested += len(out)
+            self._maybe_compact()
+            return out
 
     def _sparse_rows(self, embeddings: np.ndarray) -> list:
         """The exact sparse rows ``upsert`` will encode (same top-m path)."""

@@ -9,6 +9,7 @@ in this process, and JAX's persistent compilation cache is off around them
 (a compile for a described chip cannot be read back without one).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -119,6 +120,24 @@ def test_accumulate_kernel_compiles(one_chip, fmt_name):
         _fused_stream(fmt_name, one_chip), n_rows=SLOTS, fmt_name=fmt_name,
         stream_layout="fused", block_size=BLOCK,
     )
+
+
+@pytest.mark.parametrize("fn, name", [
+    (bscsr_topk_spmv, "bscsr_topk_spmv_multiquery"),
+    (bscsr_spmv, "bscsr_spmv"),
+])
+def test_kernels_carry_their_trace_names(one_chip, fn, name):
+    # The device trace names an op by its HLO instruction; the pallas_call's
+    # name= fixes it, whatever jitted function encloses the call.
+    kwargs = dict(n_rows=SLOTS, fmt_name="BF16", stream_layout="fused",
+                  block_size=BLOCK, interpret=False)
+    if fn is bscsr_topk_spmv:
+        kwargs["k"] = K
+    text = fn.lower(_sds((M,), jnp.float32, one_chip),
+                    _fused_stream("BF16", one_chip), **kwargs).compile().as_text()
+    calls = re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = .*custom_call_target=\"tpu_custom_call\"",
+                       text, re.M)
+    assert calls and all(re.fullmatch(rf"{name}(\.\d+)?", c) for c in calls), calls
 
 
 def test_take_gather_refuses_to_compile(one_chip):
