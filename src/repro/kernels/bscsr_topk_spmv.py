@@ -12,9 +12,9 @@ One grid step holds ``E = T*B`` stream entries as a ``(1, E)`` lane row in
 natural stream order:
 
   stage 1  decode the packet tile (shift/mask, see ``bscsr.fuse_words``), then
-           gather x with a one-hot matmul on the MXU at ``precision=HIGHEST``
-           (``gather_mode="onehot"``; ``"take"`` is an interpret-only
-           reference gather) and multiply.
+           gather x with a one-hot matmul on the MXU (``gather_mode=
+           "onehot"``; ``"take"`` is an interpret-only reference gather)
+           and multiply.
   stage 2  segment ids are the prefix count of the row-start flags, computed
            as a matmul with a triangular 0/1 matrix; per-segment sums are a
            matmul with the one-hot (segment x entry) matrix.
@@ -23,6 +23,18 @@ natural stream order:
            reductions.
   stage 4  top-k scratchpad update: k passes of ``max`` + first-``argmax``
            over the scratchpad and the step's candidates, using iota masks.
+
+Every matmul of stages 1-2 has an exact 0/1 matrix on one side, built in
+bf16, and runs as ONE bf16 MXU pass with an f32 result: the other operand is
+split three ways (``_split3``: ``hi + mid + lo == a``, 8 significant bits of
+the f32's 24 each), the pieces stacked as rows, and the three row blocks of
+the result added back.  Each product of a piece and a 0/1 entry is exact, so
+where an output has one nonzero term (the gather, the pick at each segment
+end, the accumulate kernel's placement) it is what ``precision=HIGHEST``
+(Mosaic's multi-pass fp32 contract precision) gives, bit for bit; the prefix
+and one-hot segment sums differ from it only in f32 summation order.  The
+query block is split once per call, outside the grid; the products in every
+step.
 
 ``inner_loop`` selects the stage-2 and stage-4 variants:
 
@@ -112,8 +124,6 @@ LANES = 128
 INNER_LOOPS = ("linear", "legacy", "linear-seg", "linear-topk")
 GATHER_MODES = ("onehot", "take")
 
-_HIGHEST = jax.lax.Precision.HIGHEST
-
 
 def _inner_loop_flags(inner_loop: str) -> Tuple[bool, bool]:
     """-> (prefix-difference stage-2 sums?, gated stage-4 update?)."""
@@ -139,12 +149,47 @@ def _iota(shape, dim: int) -> jnp.ndarray:
     return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
 
 
-def _dot_nt(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """a (Q, E) . b (S, E)^T -> (Q, S), f32 at full precision."""
-    return jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())),
-        precision=_HIGHEST, preferred_element_type=jnp.float32,
-    )
+NN = (((1,), (0,)), ((), ()))      # a (R, K) . b (K, N)
+NT = (((1,), (1,)), ((), ()))      # a (R, K) . b (N, K)^T
+
+
+def _bf16_head(a: jnp.ndarray) -> jnp.ndarray:
+    """The top 8 significant bits of f32 ``a``, as an f32 that bf16 holds exactly.
+
+    Masks the f32 bit pattern, which no compiler folds away: XLA may drop an
+    f32 -> bf16 -> f32 round trip as excess precision.
+    """
+    bits = jax.lax.bitcast_convert_type(a, jnp.int32)
+    return jax.lax.bitcast_convert_type(jnp.bitwise_and(bits, jnp.int32(-65536)), jnp.float32)
+
+
+def _split3(a: jnp.ndarray) -> jnp.ndarray:
+    """(R, N) f32 -> (3R, N) bf16 rows ``[hi; mid; lo]``, ``hi + mid + lo == a``.
+
+    ``hi`` is ``a`` cut to 8 significant bits and ``mid`` the remainder cut
+    to 8 more, so ``lo`` keeps the last 8 of the f32's 24: the split is
+    exact for every finite f32 whose pieces stay in bf16's normal range.
+    """
+    hi = _bf16_head(a)
+    r = a - hi
+    mid = _bf16_head(r)
+    return jnp.concatenate([hi, mid, r - mid], axis=0).astype(jnp.bfloat16)
+
+
+def _sum3(y: jnp.ndarray) -> jnp.ndarray:
+    """(3R, N) f32 results of the ``[hi; mid; lo]`` rows -> (R, N)."""
+    r = y.shape[0] // 3
+    return (y[:r] + y[r : 2 * r]) + y[2 * r :]
+
+
+def _dot_split(a3: jnp.ndarray, onehot: jnp.ndarray, dims) -> jnp.ndarray:
+    """Split rows ``a3`` (3R, K) against an exact 0/1 bf16 matrix -> (R, N) f32.
+
+    One bf16 MXU pass: every product of a bf16 piece and a 0/1 entry is
+    exact, so where an output has a single nonzero term it equals the f32
+    operand bit for bit; otherwise only the f32 summation order is the MXU's.
+    """
+    return _sum3(jax.lax.dot_general(a3, onehot, dims, preferred_element_type=jnp.float32))
 
 
 # --------------------------------------------------------------------------
@@ -235,19 +280,19 @@ def _decode_tile(stream_refs, layout: str, block: int, fmt, col_words: int):
 # Stages 1-3 (shared by every kernel) and the stage-4 k-pass.
 # --------------------------------------------------------------------------
 
-def _gather_x(x: jnp.ndarray, c: jnp.ndarray, gather_mode: str) -> jnp.ndarray:
-    """x (Q, M) at cols c (1, E) -> (Q, E); out-of-range ids gather 0."""
-    m = x.shape[1]
+def _gather_x(x3: jnp.ndarray, c: jnp.ndarray, gather_mode: str) -> jnp.ndarray:
+    """Split x3 (3Q, M) at cols c (1, E) -> x (Q, E); out-of-range ids gather 0."""
+    m = x3.shape[1]
     if gather_mode == "onehot":
-        sel = (_iota((m, c.shape[1]), 0) == c).astype(jnp.float32)   # (M, E)
-        return jnp.dot(x, sel, precision=_HIGHEST, preferred_element_type=jnp.float32)
+        sel = (_iota((m, c.shape[1]), 0) == c).astype(jnp.bfloat16)  # (M, E)
+        return _dot_split(x3, sel, NN)
     oob = (c < 0) | (c >= m)
-    xv = jnp.take(x, jnp.clip(c[0], 0, m - 1), axis=1)
-    return jnp.where(oob, 0.0, xv)
+    xv = jnp.take(x3.astype(jnp.float32), jnp.clip(c[0], 0, m - 1), axis=1)
+    return _sum3(jnp.where(oob, 0.0, xv))
 
 
-def _step_candidates(x, v, c, f, carry_row, carry_sum, *, gather_mode, prefix_sums):
-    """Stages 1-3 of one grid step.
+def _step_candidates(x3, v, c, f, carry_row, carry_sum, *, gather_mode, prefix_sums):
+    """Stages 1-3 of one grid step, for the split query block ``x3`` (3Q, M).
 
     Returns the step's candidates in segment space — ``cand_v`` (Q, S),
     ``cand_r`` (1, S) slot ids and ``complete`` (1, S) — plus the slot id of
@@ -259,23 +304,22 @@ def _step_candidates(x, v, c, f, carry_row, carry_sum, *, gather_mode, prefix_su
     s_pad = -(-(e + 1) // LANES) * LANES          # >= E+1 segment slots
 
     # ---- stage 1: gather x, multiply ----
-    prods = v * _gather_x(x, c, gather_mode)                          # (Q, E)
+    prods = v * _gather_x(x3, c, gather_mode)                         # (Q, E)
 
     # ---- stage 2: segment ids (prefix count of flags) and segment sums ----
-    tri = (_iota((e, e), 0) <= _iota((e, e), 1)).astype(jnp.float32)  # i <= j
+    tri = (_iota((e, e), 0) <= _iota((e, e), 1)).astype(jnp.bfloat16)  # i <= j
     seg = jnp.dot(
-        f.astype(jnp.bfloat16), tri.astype(jnp.bfloat16),             # exact 0/1
-        preferred_element_type=jnp.float32,
+        f.astype(jnp.bfloat16), tri, preferred_element_type=jnp.float32,  # exact 0/1
     ).astype(jnp.int32)                                               # (1, E)
-    onehot = (_iota((s_pad, e), 0) == seg).astype(jnp.float32)        # (S, E)
+    onehot = (_iota((s_pad, e), 0) == seg).astype(jnp.bfloat16)       # (S, E)
     if prefix_sums:
-        ps = jnp.dot(prods, tri, precision=_HIGHEST, preferred_element_type=jnp.float32)
+        ps = _dot_split(_split3(prods), tri, NN)
         is_last = jnp.concatenate([f[:, 1:], jnp.ones((1, 1), f.dtype)], axis=1) == 1
-        ends = _dot_nt(jnp.where(is_last, ps, 0.0), onehot)           # prefix at each end
+        ends = _dot_split(_split3(jnp.where(is_last, ps, 0.0)), onehot, NT)  # prefix at each end
         prev = jnp.concatenate([jnp.zeros((ends.shape[0], 1), jnp.float32), ends[:, :-1]], axis=1)
         seg_sums = ends - prev
     else:
-        seg_sums = _dot_nt(prods, onehot)                             # (Q, S)
+        seg_sums = _dot_split(_split3(prods), onehot, NT)             # (Q, S)
 
     # ---- stage 3: cross-step carry (paper's new_row / last_packet_output) ----
     s_last = jnp.sum(f)                          # id of the step's open segment
@@ -316,7 +360,7 @@ def _kpass(pool_v: jnp.ndarray, pool_r: jnp.ndarray, k: int):
 # --------------------------------------------------------------------------
 
 def _topk_kernel(
-    x_ref,            # (Q, M) f32 query batch (URAM analogue)
+    x_ref,            # (3Q, M) bf16 split query batch (URAM analogue)
     *refs,            # stream refs (1 fused or 3 split), outputs topv/topr
                       # (Q, k), scratch acc_v/acc_r (Q, k), carry_row (1,)
                       # SMEM, carry_sum (Q, 1) VMEM
@@ -335,7 +379,7 @@ def _topk_kernel(
     topv_ref, topr_ref, acc_v, acc_r, carry_row, carry_sum = refs[n_streams:]
     prefix_sums, gated = _inner_loop_flags(inner_loop)
     step = pl.program_id(1)
-    q = x_ref.shape[0]
+    q = x_ref.shape[0] // 3
 
     # -- per-core reset (each grid-dim-0 core owns an independent partition) --
     @pl.when(step == 0)
@@ -347,7 +391,7 @@ def _topk_kernel(
 
     v, c, f = _decode_tile(streams, stream_layout, block, fmt, col_words)
     cand_v, cand_r, complete, _ = _step_candidates(
-        x_ref[...].astype(jnp.float32), v, c, f, carry_row, carry_sum,
+        x_ref[...], v, c, f, carry_row, carry_sum,
         gather_mode=gather_mode, prefix_sums=prefix_sums,
     )
     cand_v = jnp.where(complete, cand_v, NEG_INF)
@@ -385,9 +429,9 @@ def _topk_kernel(
 # scatter live in the jnp epilogue (``ops.scatter_slot_sums``).
 
 def _accum_kernel(
-    x_ref,            # (Q, M) f32; row 0 is the query (see _query_rows)
+    x_ref,            # (3, M) bf16 split query
     *refs,            # stream refs, output y (1, n_rows), scratch y_acc
-                      # (1, L) VMEM, carry_row (1,) SMEM, carry_sum (Q, 1) VMEM
+                      # (1, L) VMEM, carry_row (1,) SMEM, carry_sum (1, 1) VMEM
     n_streams: int,
     n_rows: int,
     num_steps: int,
@@ -411,7 +455,7 @@ def _accum_kernel(
 
     v, c, f = _decode_tile(streams, stream_layout, block, fmt, col_words)
     cand_v, _, complete, row0 = _step_candidates(
-        x_ref[...].astype(jnp.float32), v, c, f, carry_row, carry_sum,
+        x_ref[...], v, c, f, carry_row, carry_sum,
         gather_mode=gather_mode, prefix_sums=prefix_sums,
     )
     # ---- stage 4': place segment s at slot row0 + s (row0 >= -1) ----
@@ -419,9 +463,8 @@ def _accum_kernel(
     width = s_pad + LANES
     base = (jnp.maximum(row0, 0) // LANES) * LANES
     place = _iota((s_pad, width), 1) == _iota((s_pad, width), 0) + (row0 - base)
-    window = jnp.dot(
-        jnp.where(complete, cand_v[:1], 0.0), place.astype(jnp.float32),
-        precision=_HIGHEST, preferred_element_type=jnp.float32,
+    window = _dot_split(
+        _split3(jnp.where(complete, cand_v, 0.0)), place.astype(jnp.bfloat16), NN,
     )
     base = pl.multiple_of(base, LANES)
     y_acc[:, pl.ds(base, width)] += window
@@ -492,20 +535,6 @@ def _stream_operands(vals, cols, flags, *, fmt_name, stream_layout, block_size,
     return static, streams, specs, (n_cores, num_steps)
 
 
-def _query_rows(x: jnp.ndarray, interpret: bool) -> jnp.ndarray:
-    """The (Q, M) f32 query block the kernel runs on.
-
-    XLA:CPU rewrites a one-row matmul as a fused multiply-reduce whose
-    summation order follows the producer fusion, so the two stream layouts
-    could then differ in the last bit; interpreted runs therefore carry a
-    duplicate of a lone query row (Mosaic runs it as given).
-    """
-    x = x.astype(jnp.float32)
-    if interpret and x.shape[0] == 1:
-        return jnp.concatenate([x, x])
-    return x
-
-
 _TOPK_STATICS = (
     "k", "n_rows", "packets_per_step", "fmt_name", "gather_mode",
     "inner_loop", "stream_layout", "block_size", "interpret",
@@ -544,32 +573,30 @@ def bscsr_topk_spmv_multiquery(
         block_size=block_size, packets_per_step=packets_per_step,
         gather_mode=gather_mode, interpret=interpret,
     )
-    nq = x.shape[0]
-    x = _query_rows(x, interpret)
-    rows, m = x.shape
+    q = x.shape[0]
+    x3 = _split3(x.astype(jnp.float32))    # constant over the grid: split once per call
     kernel = functools.partial(
         _topk_kernel, k=k, n_rows=n_rows, inner_loop=inner_loop, **static
     )
-    out_spec = pl.BlockSpec((None, rows, k), lambda c, i: (c, 0, 0))
-    v, r = pl.pallas_call(
+    out_spec = pl.BlockSpec((None, q, k), lambda c, i: (c, 0, 0))
+    return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((rows, m), lambda c, i: (0, 0)), *specs],
+        in_specs=[pl.BlockSpec(x3.shape, lambda c, i: (0, 0)), *specs],
         out_specs=[out_spec, out_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((grid[0], rows, k), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0], rows, k), jnp.int32),
+            jax.ShapeDtypeStruct((grid[0], q, k), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], q, k), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((rows, k), jnp.float32),
-            pltpu.VMEM((rows, k), jnp.int32),
+            pltpu.VMEM((q, k), jnp.float32),
+            pltpu.VMEM((q, k), jnp.int32),
             pltpu.SMEM((1,), jnp.int32),
-            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((q, 1), jnp.float32),
         ],
         interpret=interpret,
         name="bscsr_topk_spmv_multiquery",
-    )(x, *streams)
-    return v[:, :nq], r[:, :nq]
+    )(x3, *streams)
 
 
 @functools.partial(jax.jit, static_argnames=_TOPK_STATICS)
@@ -641,18 +668,18 @@ def bscsr_spmv(
     kernel = functools.partial(
         _accum_kernel, n_rows=n_rows, inner_loop=inner_loop, **static
     )
-    x = _query_rows(x[None, :], interpret)
+    x3 = _split3(x[None, :].astype(jnp.float32))
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec(x.shape, lambda c, i: (0, 0)), *specs],
+        in_specs=[pl.BlockSpec(x3.shape, lambda c, i: (0, 0)), *specs],
         out_specs=pl.BlockSpec((None, 1, n_rows), lambda c, i: (c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((grid[0], 1, n_rows), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((1, acc_len), jnp.float32),
             pltpu.SMEM((1,), jnp.int32),
-            pltpu.VMEM((x.shape[0], 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
         ],
         interpret=interpret,
         name="bscsr_spmv",
-    )(x, *streams)[:, 0]
+    )(x3, *streams)[:, 0]

@@ -113,6 +113,17 @@ def test_multiquery_kernel_compiles_q64(one_chip, fmt_name):
     )
 
 
+@pytest.mark.parametrize("inner_loop", ["linear", "legacy"])
+@pytest.mark.parametrize("q", [2, 8, 16])
+def test_multiquery_kernel_compiles_small_q_buckets(one_chip, q, inner_loop):
+    # The split query block has 3q rows, off the f32 sublane tiling for q=2.
+    _compiles(
+        bscsr_topk_spmv_multiquery, _sds((q, M), jnp.float32, one_chip),
+        _fused_stream("BF16", one_chip), k=K, n_rows=SLOTS, fmt_name="BF16",
+        inner_loop=inner_loop, stream_layout="fused", block_size=BLOCK,
+    )
+
+
 @pytest.mark.parametrize("fmt_name", ["BF16", "F32", "TAG1"])
 def test_accumulate_kernel_compiles(one_chip, fmt_name):
     _compiles(
