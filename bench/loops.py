@@ -8,6 +8,9 @@ block of ``q`` fresh queries, each waiting for the previous answer.
 update replaces an existing row through ``ingest`` on one writer thread, in
 arrival order, and once acknowledged its embedding is sent as a probe, which
 must come back as its own top-1.  Latency runs from each request's due time.
+
+Queries, and an update's new embedding, are drawn by ``gen.queries`` under
+the configuration's query law.
 """
 from __future__ import annotations
 
@@ -56,13 +59,13 @@ class ClosedBatch:
         self.svc, self.index = svc, index
         self.q = traffic["q"]
         self.rng = gen.rng_for(seed, "queries")
-        self.n_cols = cfg["n_cols"]
+        self.cfg = cfg
         self.requests: list[Request] = []
         self.acks: list[Ack] = []
         self.warm_acks: list[Ack] = []
 
     def warm(self) -> None:
-        x = gen.dense_normal(gen.rng_for(0, "warm"), self.q, self.n_cols)
+        x = gen.queries(self.cfg, gen.rng_for(0, "warm"), self.q)
         self.svc.search(x)
 
     def passes(self) -> list:
@@ -73,7 +76,7 @@ class ClosedBatch:
         end = start + seconds
         last = start
         while last < end:
-            xs = gen.dense_normal(self.rng, self.q, self.n_cols)
+            xs = gen.queries(self.cfg, self.rng, self.q)
             with _annotate("bench.search"):
                 sent = time.perf_counter()
                 vals, rows = self.svc.search(xs)
@@ -88,7 +91,6 @@ class OpenLoop:
     def __init__(self, svc, index, cfg: dict, traffic: dict, seed: int):
         self.svc, self.index, self.cfg, self.traffic = svc, index, cfg, traffic
         self.seed = seed
-        self.n_cols = cfg["n_cols"]
         self.requests: list[Request] = []
         self.acks: list[Ack] = []
         self.warm_acks: list[Ack] = []
@@ -101,12 +103,12 @@ class OpenLoop:
         rng = gen.rng_for(self.seed, "warm")
         if self.traffic.get("update_share", 0.0) > 0:
             gid = int(gen.zipf_ids(rng, 1, self.cfg["n_rows"], self.traffic["update_key_theta"])[0])
-            x = gen.dense_normal(rng, 1, self.n_cols)[0]
+            x = gen.queries(self.cfg, rng, 1)[0]
             t = time.perf_counter()
             self.svc.ingest(x[None, :], ids=[gid])
             now = time.perf_counter()
             self.warm_acks.append(Ack(gid, x, t, now, now - t))
-        xs = gen.dense_normal(rng, self.cfg["frontend"]["max_batch"], self.n_cols)
+        xs = gen.queries(self.cfg, rng, self.cfg["frontend"]["max_batch"])
         q = 1
         while q <= xs.shape[0]:
             self.index.query_batch(xs[:q])
@@ -148,7 +150,7 @@ class OpenLoop:
         traffic = dict(self.traffic, rate_per_s=rate or self.traffic["rate_per_s"])
         rng = gen.rng_for(self.seed, f"schedule{rate or ''}")
         sched = gen.open_schedule(traffic, self.cfg["n_rows"], seconds, rng)
-        xs = gen.dense_normal(rng, sched.due.shape[0], self.n_cols)
+        xs = gen.queries(self.cfg, rng, sched.due.shape[0])
         hist0 = dict(self.svc.dispatch_info()["frontend"]["batch_histogram"])
         todo: queue.Queue = queue.Queue()
         stop = threading.Event()

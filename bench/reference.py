@@ -15,28 +15,36 @@ configuration file states (the paper's §III-A approximation):
   copy keeps its slot and competes for its partition's ``k`` until
   compaction, and is dropped from the answer.
 
-Base partitions are scored on the device, one partition at a time as a dense
-``(rows, n_cols)`` block times the query block; appended rows, and the score
-of any single row, are scored on the host in float64.
+Base partitions are scored on the device, one block of rows at a time as a
+dense ``(rows, columns)`` block times the query block, over the columns that
+some query sets (all of them for dense queries); a block holds a whole
+partition where that fits ``BLOCK_BYTES``, else as many of its rows as fit,
+and the blocks' top-k are merged under the partition's tie rule.  Appended
+rows, and the score of any single row, are scored on the host in float64.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 from functools import lru_cache
 
 import numpy as np
 
 NEG = -np.inf
+BLOCK_BYTES = 1 << 30   # most bytes of one dense float32 block of rows on the device
+WIDTH_STEP = 1024       # the dense columns of a sparse query block, padded to a multiple
+IN_FLIGHT = 4           # blocks enqueued on the device ahead of the host's fetch
 
 
 def stored_values(values: np.ndarray, value_format: str) -> np.ndarray:
-    """The configured storage rounding of f32 values, returned as float32."""
+    """The configured storage rounding of f32 values, in a dtype that holds them
+    exactly: bfloat16 for BF16, else float32."""
     if value_format == "F32":
         return np.asarray(values, np.float32)
     if value_format == "BF16":
         import ml_dtypes
 
-        return np.asarray(values, np.float32).astype(ml_dtypes.bfloat16).astype(np.float32)
+        return np.asarray(values, np.float32).astype(ml_dtypes.bfloat16)
     if value_format in ("Q15", "Q7"):
         frac = 15 if value_format == "Q15" else 7
         lim = 2 ** frac
@@ -56,7 +64,7 @@ def partition_bounds(n_rows: int, partitions: int) -> np.ndarray:
 class Update:
     gid: int
     cols: np.ndarray      # sorted
-    vals: np.ndarray      # stored (rounded) values, float32
+    vals: np.ndarray      # stored (rounded) values, as ``stored_values`` gives them
     partition: int = -1
     slot: int = -1
 
@@ -121,38 +129,61 @@ class Reference:
         return float(np.dot(vals.astype(np.float64), x[cols].astype(np.float64)))
 
     # -- base partitions on the device ------------------------------------
-    def base_topk(self, xs: np.ndarray, q_block: int = 256):
-        """Per-partition top-k over the base rows: (C, Q, k) scores, global ids."""
+    def base_topk(self, xs: np.ndarray, q_block: int = 256, block_bytes: int = BLOCK_BYTES):
+        """Per-partition top-k over the base rows: (C, Q, k) scores, global ids.
+
+        Only the columns that some query sets are made dense, in rising order
+        and padded to a multiple of ``WIDTH_STEP``: the others add nothing."""
         import jax.numpy as jnp
-        import ml_dtypes
 
         c = len(self.bounds) - 1
-        sizes = np.diff(self.bounds)
-        r_max = int(sizes.max())
-        nnz_p = self.indptr[self.bounds[1:]] - self.indptr[self.bounds[:-1]]
-        nnz_cap = int(-(-int(nnz_p.max()) // 65536) * 65536)
-        fn = _partition_fn(r_max, self.n_cols, nnz_cap, self.k)
+        used = np.flatnonzero(np.any(xs != 0, axis=0))
+        width = min(self.n_cols, -(-max(used.size, 1) // WIDTH_STEP) * WIDTH_STEP)
+        slot = np.full(self.n_cols, -1, np.int32)   # each column's dense place, -1 if none
+        slot[used] = np.arange(used.size)
+        r_blk = int(min(np.diff(self.bounds).max(), max(self.k, block_bytes // (4 * width))))
+        blocks = [[(lo, min(lo + r_blk, self.bounds[p + 1]))
+                   for lo in range(self.bounds[p], self.bounds[p + 1], r_blk)] for p in range(c)]
+        nnz_b = max(int(self.indptr[hi] - self.indptr[lo]) for part in blocks for lo, hi in part)
+        nnz_cap = int(-(-nnz_b // 65536) * 65536)
+        fn = _partition_fn(r_blk, width, nnz_cap, self.k)
         q = xs.shape[0]
-        q_pad = -(-q // q_block) * q_block
-        xp = np.zeros((q_pad, self.n_cols), np.float32)
-        xp[:q] = xs
+        xp = np.zeros((-(-q // q_block) * q_block, width), np.float32)
+        xp[:q, : used.size] = xs[:, used]
+        xq = [jnp.asarray(xp[b : b + q_block]) for b in range(0, xp.shape[0], q_block)]
+        found = [[None] * len(part) for part in blocks]   # (Q, k) scores and ids per block
+        pending = collections.deque()
+
+        def fetch():
+            p, j, r0, outs = pending.popleft()
+            found[p][j] = (np.concatenate([np.asarray(s) for s, _ in outs])[:q],
+                           np.concatenate([np.asarray(i) for _, i in outs])[:q] + r0)
+
+        for p, part in enumerate(blocks):
+            for j, (r0, r1) in enumerate(part):
+                lo, hi = self.indptr[r0], self.indptr[r1]
+                flat = np.full(nnz_cap, r_blk * width, np.int32)   # past the block: dropped
+                flat[: hi - lo] = np.repeat(np.arange(r1 - r0, dtype=np.int32) * width,
+                                            np.diff(self.indptr[r0 : r1 + 1]))
+                at = slot[self.indices[lo:hi]]
+                flat[: hi - lo] = np.where(at >= 0, flat[: hi - lo] + at, r_blk * width)
+                v = np.zeros(nnz_cap, self.values.dtype)
+                v[: hi - lo] = self.values[lo:hi]
+                flat_d, v_d = jnp.asarray(flat), jnp.asarray(v).astype(jnp.float32)
+                pending.append((p, j, r0, [fn(flat_d, v_d, int(r1 - r0), x) for x in xq]))
+                if len(pending) > IN_FLIGHT:
+                    fetch()
+        while pending:
+            fetch()
         vals = np.full((c, q, self.k), NEG, np.float64)
         rows = np.zeros((c, q, self.k), np.int64)
-        for p in range(c):
-            r0, r1 = self.bounds[p], self.bounds[p + 1]
-            lo, hi = self.indptr[r0], self.indptr[r1]
-            local = np.repeat(np.arange(r1 - r0, dtype=np.int64), np.diff(self.indptr[r0 : r1 + 1]))
-            flat = np.full(nnz_cap, r_max * self.n_cols, np.int32)
-            flat[: hi - lo] = local * self.n_cols + self.indices[lo:hi]
-            v = np.zeros(nnz_cap, ml_dtypes.bfloat16 if self.cfg["value_format"] == "BF16"
-                         else np.float32)
-            v[: hi - lo] = self.values[lo:hi]
-            flat_d, v_d = jnp.asarray(flat), jnp.asarray(v).astype(jnp.float32)
-            for b in range(0, q_pad, q_block):
-                s, i = fn(flat_d, v_d, int(r1 - r0), jnp.asarray(xp[b : b + q_block]))
-                n = min(q_block, q - b)
-                vals[p, b : b + n] = np.asarray(s)[:n]
-                rows[p, b : b + n] = np.asarray(i)[:n] + r0
+        for p, part in enumerate(found):
+            # the blocks' top-k merged: score descending, earlier slot first
+            s_p = np.concatenate([s for s, _ in part], axis=1).astype(np.float64)
+            r_p = np.concatenate([r for _, r in part], axis=1).astype(np.int64)
+            order = np.lexsort((r_p, -s_p), axis=1)[:, : self.k]
+            vals[p] = np.take_along_axis(s_p, order, 1)
+            rows[p] = np.take_along_axis(r_p, order, 1)
         return vals, rows
 
     # -- the answer under the first ``n_updates`` updates ------------------

@@ -9,9 +9,11 @@ by the files ``bench/metrics/<metric>.py``.  A run makes the collection and
 the traffic from ``--seed``, builds the index through the program's entry
 (``SparseEmbeddingIndex`` under ``StreamingSimilarityService``), warms the
 shapes its traffic uses, measures for ``--seconds``, then checks every answer
-of the window against the plain reference (``reference.py``) and prints, as
-the last line of standard output, one JSON object.  The numbers compared
-and their limits (``limits.json``) close standard error and the result line.
+of the window (or the traffic's ``check_answers`` of them, drawn from the
+seed) against the plain reference (``reference.py``) and prints, as the last
+line of standard output, one JSON object.  The numbers compared and their
+limits (``limits.json``, or the configuration's ``limits``) close standard
+error and the result line.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits non-zero
 and prints no result.  JAX's compilation cache lives in
@@ -79,6 +81,12 @@ def find_cell(root: Path, name: str) -> Cell:
                 [m for m in spec["per_layer"] if mine(m)])
 
 
+def limits_for(cfg: dict) -> dict:
+    """The limits of the numbers compared: ``limits.json``, but where the
+    configuration's own ``limits`` set a number's limit."""
+    return dict(load_json(HERE / "limits.json"), **cfg.get("limits", {}))
+
+
 def load_reader(metric: str):
     path = HERE / "metrics" / f"{metric}.py"
     spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}", path)
@@ -131,13 +139,17 @@ def live_nnz(coll, acks, m: int) -> int:
     return coll.nnz - sum(lens.values()) + m * len(lens)
 
 
-def judge(cfg: dict, coll, loop, info: dict) -> dict:
-    """Compare every answer of the window with the reference; the checked numbers."""
+def judge(cfg: dict, traffic: dict, seed: int, coll, loop, info: dict) -> dict:
+    """Compare the window's answers with the reference; the checked numbers.
+
+    Every answer is compared, or, where the traffic file sets
+    ``check_answers``, that many of them drawn from the seed: the reference's
+    time grows with the answers and with the columns their queries set."""
     import bisect
 
     import numpy as np
 
-    from bench import reference
+    from bench import gen, reference
 
     ref = reference.Reference(coll.indptr, coll.indices, coll.data, coll.n_cols, cfg)
     acks = loop.warm_acks + loop.acks
@@ -146,17 +158,22 @@ def judge(cfg: dict, coll, loop, info: dict) -> dict:
     acked = [a.acked for a in acks]
     reqs = loop.requests
     answered = [r for r in reqs if r.done and not r.error and r.rows is not None]
+    checked = answered
+    n_check = traffic.get("check_answers")
+    if n_check is not None and n_check < len(answered):
+        pick = gen.rng_for(seed, "check").choice(len(answered), n_check, replace=False)
+        checked = [answered[i] for i in np.sort(pick)]
     verdict = reference.Verdict()
-    if answered:
-        xs = np.stack([r.x for r in answered])
+    if checked:
+        xs = np.stack([r.x for r in checked])
         base_vals, base_rows = ref.base_topk(xs)
         upd_scores = ref.update_scores(xs)
-        for i, r in enumerate(answered):
+        for i, r in enumerate(checked):
             lo = bisect.bisect_right(acked, r.sent)
             hi = bisect.bisect_right(acked, r.done)
             reference.judge_one(ref, r.x, r.vals, r.rows, base_vals[:, i], base_rows[:, i],
                                 upd_scores[i], lo, hi, verdict)
-    log(f"compared {verdict.compared} answers with the reference")
+    log(f"compared {verdict.compared} of {len(answered)} answers with the reference")
     checks = {
         "score_gap": verdict.score_gap,
         "row_score_gap": verdict.row_score_gap,
@@ -246,9 +263,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         if not trace:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     t = time.perf_counter()
-    checks = judge(cfg, coll, loop, info)
+    checks = judge(cfg, traffic, seed, coll, loop, info)
     log(f"reference comparison took {time.perf_counter() - t:.3f} s")
-    limits = load_json(HERE / "limits.json")
+    limits = limits_for(cfg)
     correct = all(checks[k] <= limits[k] for k in checks)
 
     result = {"correct": correct}

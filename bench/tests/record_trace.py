@@ -31,7 +31,7 @@ def main() -> int:
     cfg = dict(run.load_json(HERE.parent / "configs" / "paper-10m-bf16.json"), n_rows=200_000)
     coll = gen.make_collection(cfg, 1)
     _, svc = run.build_service(cfg, {"loop": "closed_batch"}, coll)
-    xs = gen.dense_normal(gen.rng_for(1, "queries"), 8, cfg["n_cols"])
+    xs = gen.queries(cfg, gen.rng_for(1, "queries"), 8)
     svc.search(xs)
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
