@@ -210,18 +210,25 @@ class SparseEmbeddingIndex:
         ``query`` (Q=1), ``query_batch`` and the frontend's coalesced
         passes all land here — one place that derives the executor from
         the config and routes sharded vs single-device, so the executor's
-        cache/bucket counters count every path the same way.  The query
-        block's upload and the answers' fetch are the ``index.upload`` and
-        ``index.fetch`` spans; the fetch waits for the device.
+        cache/bucket counters count every path the same way.  On the
+        single-device kernel path the executor first finds the block's set
+        columns on the host (``executor.columns``), which the kernel's
+        gather covers alone.  The query block's upload and the answers'
+        fetch are the ``index.upload`` and ``index.fetch`` spans; the fetch
+        waits for the device.
         """
         q = int(np.shape(xs)[0])
+        cols = None
+        if use_kernel and self.config.use_executor and not self.is_sharded:
+            cols = topk_lib.query_executor(self.config).set_columns(np.asarray(xs))
         with span("index.upload", q=q):
             xs = jnp.asarray(xs, jnp.float32)
+            cols = None if cols is None else jnp.asarray(cols)
         if self.is_sharded:
             v, r = self.index.query_batched(xs, use_kernel=use_kernel)
         else:
             v, r = topk_lib.topk_spmv_batched(
-                self.index, xs, use_kernel=use_kernel
+                self.index, xs, use_kernel=use_kernel, query_cols=cols
             )
         with span("index.fetch", q=q):
             return np.asarray(v), np.asarray(r)

@@ -1116,7 +1116,8 @@ def topk_spmv(
 
 
 def topk_spmv_batched(
-    index: TopKSpMVIndex, xs: jnp.ndarray, use_kernel: bool = True
+    index: TopKSpMVIndex, xs: jnp.ndarray, use_kernel: bool = True,
+    query_cols=None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Batched approximate Top-K: Q queries, one pass over the stream.
 
@@ -1125,12 +1126,15 @@ def topk_spmv_batched(
     across all Q queries (per-query bytes/nnz divided by Q — §Perf C);
     otherwise the vmapped jnp oracle evaluates the same approximation.
     With ``config.use_executor`` (default) either path dispatches through the
-    device-resident snapshot plane with power-of-two Q bucketing.
+    device-resident snapshot plane with power-of-two Q bucketing, and
+    ``query_cols`` (``QueryExecutor.set_columns``) narrows the kernel's
+    gather to the block's set columns.
     """
     cfg = index.config
     if cfg.use_executor:
         return query_executor(cfg).query_batched(
-            xs, index.packed, path="kernel" if use_kernel else "reference"
+            xs, index.packed, path="kernel" if use_kernel else "reference",
+            query_cols=query_cols,
         )
     if use_kernel:
         return kernel_ops.topk_spmv_batched(

@@ -12,9 +12,20 @@ One grid step holds ``E = T*B`` stream entries as a ``(1, E)`` lane row in
 natural stream order:
 
   stage 1  decode the packet tile (shift/mask, see ``bscsr.fuse_words``), then
-           gather x with a one-hot matmul on the MXU (``gather_mode=
+           gather x with one-hot matmuls on the MXU (``gather_mode=
            "onehot"``; ``"take"`` is an interpret-only reference gather)
-           and multiply.
+           and multiply.  The top-k kernels gather over the query block's
+           set columns only: the wrapper compacts the split query to them
+           (``query_cols``, found by ``set_columns``) in chunks of
+           ``CHUNK`` = 1024 columns; each core copies the chunks the block
+           uses into VMEM at its first step, with their ids transposed to
+           columns, and each step compares those ids with its entries'
+           columns, (CHUNK, E) per chunk.  The chunk count is data, read
+           from SMEM, so every union size runs on one compiled signature.
+           A query at most CHUNK wide is one chunk of its own width,
+           compared with an iota: the paper's 512 dense columns gather as
+           before.  The accumulate kernel gathers over all columns, (M, E)
+           against an iota.
   stage 2  segment ids are the prefix count of the row-start flags, computed
            as a matmul with a triangular 0/1 matrix; per-segment sums are a
            matmul with the one-hot (segment x entry) matrix.
@@ -67,7 +78,8 @@ squeezed, so every block's trailing two dims equal the array's; outputs are
 ``(C, Q, k)`` (top-k) or ``(C, 1, n_rows)`` (accumulate).
 
 Out-of-range col ids (padding/sentinel entries carry whatever the encoder or
-a corrupted segment left) gather 0: the one-hot row is all zero.
+a corrupted segment left) gather 0: the one-hot row is all zero, or meets
+only the zero padding of the compacted query.
 
 Scratch-shape analysis for padded (bucketed) slot counts
 --------------------------------------------------------
@@ -103,7 +115,7 @@ asserted by ``tests/test_executor.py::TestChurnStable``.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -120,6 +132,10 @@ from repro.core.quantization import (
 NEG_INF = float(np.finfo(np.float32).min)
 FLAG_WORD_BITS = 32
 LANES = 128
+
+CHUNK = 1024         # columns per stage-1 gather chunk
+BF16_ROWS = 16       # sublanes of one bf16 VMEM tile
+VMEM_BASE = 16 << 20  # Mosaic's default scoped VMEM, raised by the query chunks
 
 INNER_LOOPS = ("linear", "legacy", "linear-seg", "linear-topk")
 GATHER_MODES = ("onehot", "take")
@@ -281,7 +297,11 @@ def _decode_tile(stream_refs, layout: str, block: int, fmt, col_words: int):
 # --------------------------------------------------------------------------
 
 def _gather_x(x3: jnp.ndarray, c: jnp.ndarray, gather_mode: str) -> jnp.ndarray:
-    """Split x3 (3Q, M) at cols c (1, E) -> x (Q, E); out-of-range ids gather 0."""
+    """Split x3 (3Q, M) at cols c (1, E) -> x (Q, E); out-of-range ids gather 0.
+
+    The accumulate kernel's gather, and the top-k kernels' where one chunk
+    holds the whole query (``_gather_set_columns``).
+    """
     m = x3.shape[1]
     if gather_mode == "onehot":
         sel = (_iota((m, c.shape[1]), 0) == c).astype(jnp.bfloat16)  # (M, E)
@@ -291,8 +311,49 @@ def _gather_x(x3: jnp.ndarray, c: jnp.ndarray, gather_mode: str) -> jnp.ndarray:
     return _sum3(jnp.where(oob, 0.0, xv))
 
 
-def _step_candidates(x3, v, c, f, carry_row, carry_sum, *, gather_mode, prefix_sums):
-    """Stages 1-3 of one grid step, for the split query block ``x3`` (3Q, M).
+def _column(row: jnp.ndarray) -> jnp.ndarray:
+    """(1, N) int32 lane row -> (N, 1) column, by one 32-bit 2-D transpose."""
+    return jnp.transpose(jnp.broadcast_to(row, (8, row.shape[1])))[:, :1]
+
+
+def _gather_set_columns(x_chunks, id_cols, n_chunks, c: jnp.ndarray, q: int,
+                        gather_mode: str) -> jnp.ndarray:
+    """x (Q, E) at cols c (1, E), gathered over the query block's set columns.
+
+    ``x_chunks`` (3Q + pad, n_max * width) holds the split query compacted
+    to the block's set columns and ``id_cols`` (n_max, width, 1) their
+    column ids (-1 past the last), as columns; only the first ``n_chunks``
+    chunks are read.  Chunk j's (width, E) one-hot compares its ids with
+    each entry's column, so an entry whose column no query sets, or an
+    out-of-range id, gathers 0.  The ids are distinct, so each entry's
+    one-hot has at most one 1 across all chunks: the chunk sums add one
+    nonzero term to zeros, and the result is the full-width one-hot's, bit
+    for bit.  A query at most CHUNK wide is its own chunk (``_compact_query``
+    leaves it whole), gathered by ``_gather_x``.
+    """
+    n_max, chunk, _ = id_cols.shape
+    rows = x_chunks.shape[0]
+    e = c.shape[1]
+    if n_max == 1:
+        return _gather_x(x_chunks[: 3 * q, :], c, gather_mode)
+    if gather_mode == "onehot":
+
+        def add_chunk(j, acc):
+            sel = (id_cols[j] == c).astype(jnp.bfloat16)               # (width, E)
+            xj = x_chunks[:, pl.ds(pl.multiple_of(j * chunk, chunk), chunk)]
+            return acc + jax.lax.dot_general(xj, sel, NN, preferred_element_type=jnp.float32)
+
+        acc = jax.lax.fori_loop(0, n_chunks, add_chunk, jnp.zeros((rows, e), jnp.float32))
+        return _sum3(acc[: 3 * q])
+    ids = id_cols[...].reshape(n_max * chunk)
+    xs = x_chunks[: 3 * q, :].astype(jnp.float32)
+    hit = (ids[:, None] == c[0][None, :]) & (_iota((n_max * chunk, 1), 0) < n_chunks * chunk)
+    xv = jnp.take(xs, jnp.argmax(hit, axis=0), axis=1)
+    return _sum3(jnp.where(jnp.any(hit, axis=0)[None, :], xv, 0.0))
+
+
+def _step_candidates(xg, v, f, carry_row, carry_sum, *, prefix_sums):
+    """Stages 1-3 of one grid step, for the gathered query values ``xg`` (Q, E).
 
     Returns the step's candidates in segment space — ``cand_v`` (Q, S),
     ``cand_r`` (1, S) slot ids and ``complete`` (1, S) — plus the slot id of
@@ -300,11 +361,11 @@ def _step_candidates(x3, v, c, f, carry_row, carry_sum, *, gather_mode, prefix_s
     left open by the previous step; the step's last segment stays open.
     """
     e = v.shape[0] * v.shape[1]
-    v, c, f = v.reshape(1, e), c.reshape(1, e), f.reshape(1, e)
+    v, f = v.reshape(1, e), f.reshape(1, e)
     s_pad = -(-(e + 1) // LANES) * LANES          # >= E+1 segment slots
 
-    # ---- stage 1: gather x, multiply ----
-    prods = v * _gather_x(x3, c, gather_mode)                         # (Q, E)
+    # ---- stage 1: multiply the gathered x ----
+    prods = v * xg                                                    # (Q, E)
 
     # ---- stage 2: segment ids (prefix count of flags) and segment sums ----
     tri = (_iota((e, e), 0) <= _iota((e, e), 1)).astype(jnp.bfloat16)  # i <= j
@@ -360,10 +421,14 @@ def _kpass(pool_v: jnp.ndarray, pool_r: jnp.ndarray, k: int):
 # --------------------------------------------------------------------------
 
 def _topk_kernel(
-    x_ref,            # (3Q, M) bf16 split query batch (URAM analogue)
+    nch_ref,          # (1,) SMEM: chunks of the block's set columns to read
+    ids_ref,          # (n_max, width) int32 set-column ids, -1 past the last
+    x_hbm,            # (3Q + pad, n_max * width) bf16 compacted split query
     *refs,            # stream refs (1 fused or 3 split), outputs topv/topr
-                      # (Q, k), scratch acc_v/acc_r (Q, k), carry_row (1,)
-                      # SMEM, carry_sum (Q, 1) VMEM
+                      # (Q, k), scratch x_vmem like x_hbm (URAM
+                      # analogue), id_cols (n_max, width, 1) the ids as
+                      # columns, acc_v/acc_r (Q, k), carry_row (1,) SMEM,
+                      # carry_sum (Q, 1) VMEM
     n_streams: int,
     k: int,
     n_rows: int,
@@ -376,10 +441,12 @@ def _topk_kernel(
     col_words: int,
 ):
     streams = refs[:n_streams]
-    topv_ref, topr_ref, acc_v, acc_r, carry_row, carry_sum = refs[n_streams:]
+    topv_ref, topr_ref, x_vmem, id_cols, acc_v, acc_r, carry_row, carry_sum = (
+        refs[n_streams:])
     prefix_sums, gated = _inner_loop_flags(inner_loop)
     step = pl.program_id(1)
-    q = x_ref.shape[0] // 3
+    q = acc_v.shape[0]
+    n_chunks = nch_ref[0]
 
     # -- per-core reset (each grid-dim-0 core owns an independent partition) --
     @pl.when(step == 0)
@@ -389,10 +456,23 @@ def _topk_kernel(
         carry_row[0] = -1
         carry_sum[...] = jnp.zeros((q, 1), jnp.float32)
 
+        if ids_ref.shape[0] == 1:    # the query's own chunk, against an iota
+            pltpu.sync_copy(x_hbm, x_vmem)
+        else:
+
+            def load(j, carry):      # only the chunks the block sets reach VMEM
+                cols = pl.ds(pl.multiple_of(j * CHUNK, CHUNK), CHUNK)
+                pltpu.sync_copy(x_hbm.at[:, cols], x_vmem.at[:, cols])
+                id_cols[j] = _column(ids_ref[pl.ds(j, 1), :])
+                return carry
+
+            jax.lax.fori_loop(0, n_chunks, load, 0)
+
     v, c, f = _decode_tile(streams, stream_layout, block, fmt, col_words)
+    c = c.reshape(1, -1)
+    xg = _gather_set_columns(x_vmem, id_cols, n_chunks, c, q, gather_mode)
     cand_v, cand_r, complete, _ = _step_candidates(
-        x_ref[...], v, c, f, carry_row, carry_sum,
-        gather_mode=gather_mode, prefix_sums=prefix_sums,
+        xg, v, f, carry_row, carry_sum, prefix_sums=prefix_sums,
     )
     cand_v = jnp.where(complete, cand_v, NEG_INF)
 
@@ -455,8 +535,8 @@ def _accum_kernel(
 
     v, c, f = _decode_tile(streams, stream_layout, block, fmt, col_words)
     cand_v, _, complete, row0 = _step_candidates(
-        x_ref[...], v, c, f, carry_row, carry_sum,
-        gather_mode=gather_mode, prefix_sums=prefix_sums,
+        _gather_x(x_ref[...], c.reshape(1, -1), gather_mode), v, f,
+        carry_row, carry_sum, prefix_sums=prefix_sums,
     )
     # ---- stage 4': place segment s at slot row0 + s (row0 >= -1) ----
     s_pad = cand_v.shape[1]
@@ -535,6 +615,72 @@ def _stream_operands(vals, cols, flags, *, fmt_name, stream_layout, block_size,
     return static, streams, specs, (n_cores, num_steps)
 
 
+def chunk_capacity(n_cols: int) -> int:
+    """Gather chunks that hold every column of an ``n_cols``-wide query."""
+    return -(-n_cols // CHUNK)
+
+
+def set_columns(xs: np.ndarray) -> Tuple[Optional[np.ndarray], int]:
+    """A (Q, M) host query block -> (its set columns, their count).
+
+    The columns are those some query of the block sets (any nonzero), in
+    ascending order and padded with -1 to ``chunk_capacity(M) * CHUNK``: the
+    ``query_cols`` that ``bscsr_topk_spmv_multiquery`` takes; None where one
+    chunk holds the whole query, which the kernel gathers whole.
+    """
+    used = np.flatnonzero(np.any(np.asarray(xs) != 0, axis=0)).astype(np.int32)
+    n_max = chunk_capacity(xs.shape[1])
+    if n_max == 1:
+        return None, int(used.size)
+    cols = np.full(n_max * CHUNK, -1, np.int32)
+    cols[: used.size] = used
+    return cols, int(used.size)
+
+
+def gather_width(n_set: int, n_cols: int) -> int:
+    """Columns the stage-1 gather covers for ``n_set`` set columns of ``n_cols``."""
+    if chunk_capacity(n_cols) == 1:
+        return n_cols
+    return max(1, -(-n_set // CHUNK)) * CHUNK
+
+
+def _compact_query(x: jnp.ndarray, query_cols):
+    """(Q, M) f32 -> (chunk count (1,), ids (n_max, width), x (3Q + pad,
+    n_max * width) bf16): the split query at its set columns, in chunks.
+
+    A query at most ``CHUNK`` wide is one chunk of its own width, whole:
+    compaction cannot narrow it.  Wider ones are compacted to ``query_cols``
+    (``None`` takes every column) in chunks of ``CHUNK``, padded with id -1
+    and zeros; at least one chunk is read, so a block that sets no column
+    gathers its first, all-zero chunk.  The rows are padded to whole bf16
+    tiles, for the chunk copies.
+    """
+    q, m = x.shape
+    n_max = chunk_capacity(m)
+    if query_cols is not None and query_cols.shape != (n_max * CHUNK,):
+        raise ValueError(
+            f"query_cols must have shape {(n_max * CHUNK,)} for {m} columns, "
+            f"got {query_cols.shape}"
+        )
+    if n_max == 1:
+        n_chunks = jnp.ones((1,), jnp.int32)
+        ids = jnp.arange(m, dtype=jnp.int32)[None, :]
+        x3 = _split3(x)
+    else:
+        lanes = jnp.arange(n_max * CHUNK, dtype=jnp.int32)
+        if query_cols is None:
+            query_cols = jnp.where(lanes < m, lanes, -1)
+        query_cols = jnp.asarray(query_cols, jnp.int32)
+        live = query_cols >= 0
+        n_chunks = jnp.maximum(1, (jnp.sum(live, dtype=jnp.int32) + CHUNK - 1) // CHUNK)
+        n_chunks = n_chunks.reshape(1)
+        ids = query_cols.reshape(n_max, CHUNK)
+        x3 = _split3(jnp.where(live[None, :], jnp.take(x, jnp.clip(query_cols, 0, m - 1), axis=1), 0.0))
+    pad = -x3.shape[0] % BF16_ROWS
+    x3 = jnp.concatenate([x3, jnp.zeros((pad, x3.shape[1]), x3.dtype)], axis=0)
+    return n_chunks, ids, x3
+
+
 _TOPK_STATICS = (
     "k", "n_rows", "packets_per_step", "fmt_name", "gather_mode",
     "inner_loop", "stream_layout", "block_size", "interpret",
@@ -547,6 +693,7 @@ def bscsr_topk_spmv_multiquery(
     vals: jnp.ndarray,     # split: (C, P, B) storage dtype; fused: (C, P, W) i32
     cols: jnp.ndarray = None,   # (C, P, B) int16/int32 (split only)
     flags: jnp.ndarray = None,  # (C, P, B//32) int32   (split only)
+    query_cols: jnp.ndarray = None,  # (n_max * CHUNK,) int32, see set_columns
     *,
     k: int,
     n_rows: int,           # per-core slot budget (uniform; may be a bucketed
@@ -567,6 +714,11 @@ def bscsr_topk_spmv_multiquery(
     name a tagged width class (``TAG4``/``TAG2``/``TAG1``) for one group of a
     mixed-precision snapshot — fused layout only.  ``interpret`` is required:
     ``False`` compiles with Mosaic, ``True`` runs the Pallas interpreter.
+
+    ``query_cols`` lists the columns the block's queries set (``set_columns``
+    finds them on the host); a column left out must be 0 in every query.
+    It is data: every column count runs on one compiled signature, and the
+    gather reads only the chunks that hold them.  ``None`` takes all columns.
     """
     static, streams, specs, grid = _stream_operands(
         vals, cols, flags, fmt_name=fmt_name, stream_layout=stream_layout,
@@ -574,29 +726,40 @@ def bscsr_topk_spmv_multiquery(
         gather_mode=gather_mode, interpret=interpret,
     )
     q = x.shape[0]
-    x3 = _split3(x.astype(jnp.float32))    # constant over the grid: split once per call
+    # constant over the grid: compacted and split once per call
+    n_chunks, ids, x3 = _compact_query(x.astype(jnp.float32), query_cols)
     kernel = functools.partial(
         _topk_kernel, k=k, n_rows=n_rows, inner_loop=inner_loop, **static
     )
     out_spec = pl.BlockSpec((None, q, k), lambda c, i: (c, 0, 0))
+    # the chunks, and their ids as (width, 1) columns, whole (8, 128) tiles each
+    chunk_bytes = x3.size * x3.dtype.itemsize + ids.size * LANES * 4
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec(x3.shape, lambda c, i: (0, 0)), *specs],
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(ids.shape, lambda c, i: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            *specs,
+        ],
         out_specs=[out_spec, out_spec],
         out_shape=[
             jax.ShapeDtypeStruct((grid[0], q, k), jnp.float32),
             jax.ShapeDtypeStruct((grid[0], q, k), jnp.int32),
         ],
         scratch_shapes=[
+            pltpu.VMEM(x3.shape, jnp.bfloat16),
+            pltpu.VMEM(ids.shape + (1,), jnp.int32),
             pltpu.VMEM((q, k), jnp.float32),
             pltpu.VMEM((q, k), jnp.int32),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((q, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BASE + chunk_bytes),
         interpret=interpret,
         name="bscsr_topk_spmv_multiquery",
-    )(x3, *streams)
+    )(n_chunks, ids, x3, *streams)
 
 
 @functools.partial(jax.jit, static_argnames=_TOPK_STATICS)
@@ -605,6 +768,7 @@ def bscsr_topk_spmv(
     vals: jnp.ndarray,
     cols: jnp.ndarray = None,
     flags: jnp.ndarray = None,
+    query_cols: jnp.ndarray = None,
     *,
     k: int,
     n_rows: int,
@@ -618,7 +782,7 @@ def bscsr_topk_spmv(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One query: the multi-query kernel at Q=1; per-core (vals, rows), (C, k)."""
     v, r = bscsr_topk_spmv_multiquery(
-        x[None, :], vals, cols, flags, k=k, n_rows=n_rows, interpret=interpret,
+        x[None, :], vals, cols, flags, query_cols, k=k, n_rows=n_rows, interpret=interpret,
         packets_per_step=packets_per_step, fmt_name=fmt_name,
         gather_mode=gather_mode, inner_loop=inner_loop,
         stream_layout=stream_layout, block_size=block_size,

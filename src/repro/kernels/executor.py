@@ -72,7 +72,9 @@ from repro.kernels.bscsr_topk_spmv import (
     bscsr_spmv,
     bscsr_topk_spmv,
     bscsr_topk_spmv_multiquery,
+    gather_width,
 )
+from repro.kernels.bscsr_topk_spmv import set_columns as find_set_columns
 from repro.utils.tracing import span
 
 # (snapshot uid, stream layout) -> DeviceSnapshot; entries evicted when the
@@ -345,6 +347,10 @@ class QueryExecutor:
         # without parsing ``fn_builds``.
         self.q_bucket_hits = 0
         self.q_exact_hits = 0
+        # Stage-1 gather width (columns: chunks x CHUNK, or the query's own
+        # width when one chunk holds it) -> dispatches, for the blocks whose
+        # set columns ``set_columns`` found.
+        self.gather_widths: dict = {}
 
     # -- dispatch ------------------------------------------------------------
 
@@ -420,6 +426,21 @@ class QueryExecutor:
         self._pinned &= set(_DEVICE_CACHE.keys())
         return live
 
+    def set_columns(self, xs: np.ndarray) -> Optional[np.ndarray]:
+        """The set columns of a host (Q, M) query block: ``query_cols``.
+
+        Found on the host before the block's upload, so the gather width is
+        known without waiting for the device; the width is data to the
+        compiled fn, so a block of any union size reuses it.  None where
+        one gather chunk holds the whole query.
+        """
+        with span("executor.columns", q=int(xs.shape[0])) as s:
+            cols, n_set = find_set_columns(xs)
+            width = gather_width(n_set, xs.shape[1])
+            s.set_metadata(cols=n_set, width=width)
+        self.gather_widths[width] = self.gather_widths.get(width, 0) + 1
+        return cols
+
     def query(
         self,
         x: jnp.ndarray,
@@ -437,7 +458,7 @@ class QueryExecutor:
             row_map=row_map, row_map_key=row_map_key, device=device,
         )
         self.dispatches += 1
-        return fn(x, *snap.call_args(n_rows))
+        return fn(x, None, *snap.call_args(n_rows))
 
     def query_batched(
         self,
@@ -449,8 +470,13 @@ class QueryExecutor:
         row_map_key=None,
         device=None,
         n_rows=None,
+        query_cols=None,
     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """(Q, big_k) answers for a (Q, M) batch, one pass over the stream."""
+        """(Q, big_k) answers for a (Q, M) batch, one pass over the stream.
+
+        ``query_cols`` (from ``set_columns``) narrows the kernel's gather to
+        the block's set columns; ``None`` gathers over every column.
+        """
         xs = jnp.asarray(xs)
         if xs.ndim != 2 or xs.shape[0] == 0:
             raise ValueError(
@@ -472,7 +498,7 @@ class QueryExecutor:
             self.dispatches += 1
             if bucket != q:
                 xs = _query_padder(bucket - q)(xs)
-            vals, rows = fn(xs, *snap.call_args(n_rows))
+            vals, rows = fn(xs, query_cols, *snap.call_args(n_rows))
             return _query_unpadder(q)(vals, rows) if bucket != q else (vals, rows)
 
     def spmv(
@@ -522,6 +548,7 @@ class QueryExecutor:
             "device_snapshots_process_wide": device_cache_size(),
             "interpret": self.interpret,                # False: Mosaic-compiled
             "gather_mode": self.gather_mode,
+            "gather_widths": dict(sorted(self.gather_widths.items())),
             "paths": sorted({key[0] for key in self._fns}),  # compiled fn paths
         }
 
@@ -553,7 +580,7 @@ class QueryExecutor:
 
         if path == "reference":
 
-            def run(x, *arrs):
+            def run(x, query_cols, *arrs):
                 streams, row_starts, rows_per, n_rows, slot, tombs, rmap = (
                     split_args(arrs)
                 )
@@ -590,7 +617,7 @@ class QueryExecutor:
                 # core index vectors are static (baked into the trace).
                 num_cores = snap.num_cores
 
-                def run(x, *arrs):
+                def run(x, query_cols, *arrs):
                     streams, row_starts, rows_per, n_rows, slot, tombs, rmap = (
                         split_args(arrs)
                     )
@@ -604,7 +631,8 @@ class QueryExecutor:
                         snap.groups_meta, streams
                     ):
                         gv, gr = kernel(
-                            xq, words, **dict(kwargs, fmt_name=cname)
+                            xq, words, query_cols=query_cols,
+                            **dict(kwargs, fmt_name=cname),
                         )
                         idx = jnp.asarray(list(cores), jnp.int32)
                         lv = lv.at[idx].set(gv)
@@ -620,12 +648,13 @@ class QueryExecutor:
 
             else:
 
-                def run(x, *arrs):
+                def run(x, query_cols, *arrs):
                     streams, row_starts, rows_per, n_rows, slot, tombs, rmap = (
                         split_args(arrs)
                     )
                     lv, lr = kernel(
-                        jnp.asarray(x, jnp.float32), *streams, **kwargs
+                        jnp.asarray(x, jnp.float32), *streams,
+                        query_cols=query_cols, **kwargs,
                     )
                     finalize = (
                         ops.finalize_candidates if q is None

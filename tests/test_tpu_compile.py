@@ -2,7 +2,8 @@
 
 Nothing runs: each test lowers a kernel with ``interpret=False`` at the
 deployment widths of ``configs/topk_spmv.CONFIG`` (M=512, block 256, BF16,
-fused stream, k=8) and compiles it for one chip of a ``v5e:2x2`` topology
+fused stream, k=8), and the top-k kernel also at the learned-sparse width
+(M=30,522), and compiles it for one chip of a ``v5e:2x2`` topology
 that is described, not attached.  The topology is described inside a
 fixture, so collecting this file never loads the TPU library; compiles stay
 in this process, and JAX's persistent compilation cache is off around them
@@ -19,10 +20,12 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import bscsr
 from repro.core.quantization import FORMATS, WIDTH_CLASSES
 from repro.kernels.bscsr_topk_spmv import (
+    CHUNK,
     INNER_LOOPS,
     bscsr_spmv,
     bscsr_topk_spmv,
     bscsr_topk_spmv_multiquery,
+    chunk_capacity,
 )
 
 M, BLOCK, K = 512, 256, 8
@@ -110,6 +113,19 @@ def test_multiquery_kernel_compiles_q64(one_chip, fmt_name):
         bscsr_topk_spmv_multiquery, _sds((64, M), jnp.float32, one_chip),
         _fused_stream(fmt_name, one_chip), k=K, n_rows=SLOTS,
         fmt_name=fmt_name, stream_layout="fused", block_size=BLOCK,
+    )
+
+
+@pytest.mark.parametrize("m, q", [(M, 64), (30522, 64), (30522, 1)])
+def test_multiquery_kernel_compiles_with_set_columns(one_chip, m, q):
+    # The learned-sparse width (30,522 columns): the compacted query block
+    # is held in VMEM whole, its set-column ids are data.  A one-hot over
+    # all 30,522 columns would need 26.4 MB of scoped VMEM against 16 MB.
+    _compiles(
+        bscsr_topk_spmv_multiquery, _sds((q, m), jnp.float32, one_chip),
+        _fused_stream("BF16", one_chip), None, None,
+        _sds((chunk_capacity(m) * CHUNK,), jnp.int32, one_chip), k=K,
+        n_rows=SLOTS, fmt_name="BF16", stream_layout="fused", block_size=BLOCK,
     )
 
 

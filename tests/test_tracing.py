@@ -21,7 +21,8 @@ N_COLS = 64
 
 @pytest.fixture(scope="module")
 def events(tmp_path_factory):
-    """[(line index, name, start_ns, end_ns, stats)] of every ``repro.`` span."""
+    """[(line index, name, start_ns, end_ns, stats)] of every ``repro.`` span,
+    in start order."""
     rng = np.random.default_rng(0)
     dense = rng.standard_normal((300, N_COLS)).astype(np.float32)
     # a big_k no other test uses: the interned executor builds afresh
@@ -51,7 +52,8 @@ def events(tmp_path_factory):
     for i, line in enumerate(lines):
         got += [(i, e.name[len(PREFIX):], e.start_ns, e.end_ns, dict(e.stats))
                 for e in line.events if e.name.startswith(PREFIX)]
-    return got
+    # in start order: the profiler may list the frontend thread's line first
+    return sorted(got, key=lambda e: e[2])
 
 
 def _named(events, name):
@@ -95,6 +97,15 @@ def test_search_spans_nest_and_carry_ids(events):
     assert builds[0][4] == {"path": "kernel", "q": 4, "retrace": 0}
     compiles = _named(events, "executor.compile")
     assert len(compiles) == len(builds)          # one first call per built fn
+
+
+def test_each_pass_finds_its_set_columns(events):
+    # two searches of 3 dense queries and one frontend pass of 1, each
+    # setting all 64 columns: one gather chunk, 64 wide
+    cols = _named(events, "executor.columns")
+    assert [(_parent(events, c), c[4]["q"]) for c in cols] == [
+        ("service.search", 3), ("service.search", 3), ("frontend.flush", 1)]
+    assert all(c[4]["cols"] == N_COLS and c[4]["width"] == N_COLS for c in cols)
 
 
 def test_ingest_wraps_its_refresh(events):
