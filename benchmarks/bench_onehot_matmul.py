@@ -5,9 +5,10 @@
 
 A standalone Pallas kernel runs, in every grid step, the three matmuls of
 stages 1-2 of ``bscsr_topk_spmv_multiquery`` at the deployment widths
-(Q=64 queries, M=512 columns, E=512 stream entries per step, S=640 segment
-slots), with the step's masks built in the kernel from a streamed column and
-row-start row, two ways:
+(Q=64 queries, M=512 columns, E=512 stream entries per step, S=128 segment
+slots: the bound of rows that average 20 entries, where one slot per entry
+would take 640), with the step's masks built in the kernel from a streamed
+column and row-start row, two ways:
 
   f32_highest  f32 0/1 matrices, every dot at ``precision=HIGHEST`` (Mosaic:
                fp32 contract precision);
@@ -46,7 +47,7 @@ from repro.kernels.bscsr_topk_spmv import (  # noqa: E402
     _split3,
 )
 
-Q, M, E, S = 64, 512, 512, 640
+Q, M, E, S = 64, 512, 512, 128
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 

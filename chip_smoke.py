@@ -202,6 +202,8 @@ def single_chip(args) -> None:
     check(info["interpret"] is False, "kernels compiled by Mosaic (interpret False)")
     check("kernel" in info["paths"] and info["gather_mode"] == "onehot",
           "kernel path built with the one-hot MXU gather")
+    check(set(info["seg_slots"]) == {128},
+          f"stage-2 segment space 128 of 640 on every pin ({info['seg_slots']})")
     check(info["retraces"] == warm["retraces"] and info["fn_builds"] == warm["fn_builds"],
           f"zero retraces and builds after warm-up (retraces {warm['retraces']} -> "
           f"{info['retraces']}, builds {warm['fn_builds']} -> {info['fn_builds']})")

@@ -784,6 +784,7 @@ class _SpmdDispatcher:
         # mirrors QueryExecutor.cache_info (docs/SERVING.md).
         self.q_bucket_hits = 0
         self.q_exact_hits = 0
+        self.seg_slots = None      # the last synced signature's segment space
 
     # -- device sync ---------------------------------------------------------
 
@@ -876,15 +877,23 @@ class _SpmdDispatcher:
             "gsent", np.asarray(o._next_gid, np.int32), o._next_gid
         )
         args = tuple(arrs) + (gsent,)
+        # One compiled fn serves every shard: the segment space is the
+        # largest shard's, checked against each before it is served.
+        t = o.config.packets_per_step
+        seg_slots = max(p.seg_slots(t) for p in packs)
+        for p in packs:
+            kernel_ops.check_segment_slots(p, t, seg_slots)
+        self.seg_slots = seg_slots
         sig = (
             self.layout,
             tuple((a.shape, str(a.dtype)) for a in args),
+            seg_slots,
         )
         return args, sig
 
     # -- compiled fn ---------------------------------------------------------
 
-    def _build_spmv(self, n_out: int, args):
+    def _build_spmv(self, n_out: int, args, seg_slots: int):
         """One compiled accumulate fn: per-shard kernel + global-row scatter,
         reduced with a dense ``psum`` over the shard axis (no top-k merge).
 
@@ -909,7 +918,7 @@ class _SpmdDispatcher:
             gather_mode=self._gather,
             inner_loop=cfg.inner_loop,
             stream_layout=layout, block_size=pack0.block_size,
-            interpret=self._interpret,
+            seg_slots=seg_slots, interpret=self._interpret,
         )
 
         def body(x, alpha, beta, y, *arrs):
@@ -945,9 +954,9 @@ class _SpmdDispatcher:
             out_shardings=NamedSharding(mesh, out_specs),
         )
 
-    def _build(self, q: Optional[int], args):
+    def _build(self, q: Optional[int], args, seg_slots: int):
         if isinstance(q, tuple) and q[0] == "spmv":
-            return self._build_spmv(q[1], args)
+            return self._build_spmv(q[1], args, seg_slots)
         o = self.owner
         cfg = o.config
         mesh = self.mesh
@@ -967,7 +976,7 @@ class _SpmdDispatcher:
             gather_mode=self._gather,
             inner_loop=cfg.inner_loop,
             stream_layout=layout, block_size=pack0.block_size,
-            interpret=self._interpret,
+            seg_slots=seg_slots, interpret=self._interpret,
         )
 
         def merge_pair(v1, r1, v2, r2, gsent):
@@ -1051,7 +1060,7 @@ class _SpmdDispatcher:
             # A signature change means a common bucket moved: every cached
             # fn of the old signature is stale, drop them all.
             self._fns = {kk: f for kk, f in self._fns.items() if kk[1] == sig}
-            fn = self._build(q, args)
+            fn = self._build(q, args, sig[2])
             self._fns[key] = fn
             self.fn_builds += 1
             prev = self._last_sig.get(q)
@@ -1133,5 +1142,6 @@ class _SpmdDispatcher:
             "q_exact_hits": self.q_exact_hits,
             "interpret": self._interpret,
             "gather_mode": self._gather,
+            "seg_slots": {} if self.seg_slots is None else {self.seg_slots: 1},
             "bundle": self.bundle.counters(),
         }

@@ -372,7 +372,9 @@ class SparseEmbeddingIndex:
         """Cache + signature stats of the executor serving this config.
 
         Executor counters (``compiled_fns``, ``fn_builds``, ``retraces``,
-        ``dispatches``, device-pin counts) merged with the current
+        ``dispatches``, device-pin counts, ``gather_widths``, and
+        ``seg_slots``: the kernels' segment space per pinned signature)
+        merged with the current
         snapshot's ``signature_info()`` — the bucketed dims that key
         compiled query fns vs the live counts inside them.  Steady-state
         serve-while-ingest shows ``retraces`` flat while versions climb;

@@ -277,6 +277,12 @@ class MutableTopKSpMVIndex:
         # per-partition padded tagged-word cache: ci -> (cap, fmt, words).
         self._class_caps: Optional[dict] = None
         self._padded_tagged = [None] * c
+        # Churn-stable floor of the kernels' segment space (same anchor-
+        # then-grow discipline), from each partition's most row starts in
+        # one grid step, kept per partition so a refresh rescans only the
+        # mutated ones.
+        self._seg_cap = -1
+        self._part_starts = [0] * c
         # All partitions' content is new: stamp them past every COW buffer.
         self._stamp_counter += 1
         self._part_stamps = np.full(c, self._stamp_counter, np.int64)
@@ -394,6 +400,27 @@ class MutableTopKSpMVIndex:
             padded = bscsr_lib.pad_packets(self._streams[ci], max_p)
             self._padded_streams[ci] = padded
             self._padded_words[ci] = bscsr_lib.fuse_stream(padded) if fused else None
+            self._part_starts[ci] = kernel_ops.max_step_row_starts(
+                self._streams[ci].flags[None], mult
+            )
+        # The kernels' segment space: a step's row starts plus the carried
+        # row, in whole lanes.  Churn-stable, it only grows (in steps of
+        # 128 lanes) until compact() re-anchors it, so an upsert that stays
+        # inside the bucket keeps the compiled signature; the served bound
+        # is still never below the snapshot's own (PackedPartitions.seg_slots).
+        seg = kernel_ops.segment_slots(
+            max(self._part_starts), mult * self._streams[0].block_size
+        )
+        if not self.config.churn_stable:
+            seg_floor = 0
+        else:
+            if preserve_caps and self._seg_cap >= 0:
+                pass  # checkpoint restore: the saved floor is authoritative
+            elif self._seg_cap < 0:
+                self._seg_cap = seg                   # anchor refresh: exact
+            else:
+                self._seg_cap = max(self._seg_cap, seg)
+            seg_floor = self._seg_cap
         self._padded_max_p = max_p
         self._dirty = set()
         self.last_refresh_repadded = len(dirty)
@@ -506,6 +533,7 @@ class MutableTopKSpMVIndex:
             tombstone_count=self._tombstone_slots,
             fmt_codes=fmt_codes,
             groups=groups,
+            seg_slots_floor=seg_floor,
         )
         if self.config.cow_snapshots:
             buf, copied = self._buffer_pool.lease(
@@ -943,6 +971,7 @@ class MutableTopKSpMVIndex:
                 {k: int(v) for k, v in self._class_caps.items()}
                 if self._class_caps is not None else None
             ),
+            "seg_cap": int(self._seg_cap),
             "part_fmts": (
                 list(self._part_fmts) if self._part_fmts is not None else None
             ),
@@ -1062,6 +1091,7 @@ class MutableTopKSpMVIndex:
         # Restore the churn-stable caps verbatim, then build the snapshot
         # around them (preserve_caps): same padded shapes as at export.
         obj._packet_cap = int(meta["packet_cap"])
+        obj._seg_cap = int(meta.get("seg_cap", -1))
         if meta["class_caps"] is not None:
             obj._class_caps = {
                 k: int(v) for k, v in meta["class_caps"].items()

@@ -28,7 +28,15 @@ natural stream order:
            against an iota.
   stage 2  segment ids are the prefix count of the row-start flags, computed
            as a matmul with a triangular 0/1 matrix; per-segment sums are a
-           matmul with the one-hot (segment x entry) matrix.
+           matmul with the one-hot (segment x entry) matrix, (S, E).  Its
+           segment space S is the static ``seg_slots``: the stream's most
+           row starts in any one step, plus segment 0 (the row carried in),
+           rounded up to 128 lanes and capped at ``seg_slot_cap(E)``, the
+           one-row-per-entry size.  ``ops.PackedPartitions.seg_slots``
+           derives it on the host from the flags that are pinned; ``None``
+           takes the cap.  A segment id >= S would be dropped without a
+           trace, so the bound is checked on the host before the stream is
+           served (``ops.check_segment_slots``).
   stage 3  cross-step carry (current row id in SMEM, per-query partial sum —
            the paper's ``new_row`` / ``last_packet_output``), read with masked
            reductions.
@@ -111,6 +119,20 @@ materialized at NEG_INF:
 Net: padded and unpadded paths are bit-identical end to end, on every
 inner_loop x stream_layout, including all-negative-score matrices —
 asserted by ``tests/test_executor.py::TestChurnStable``.
+
+The segment space is sized the same way.  Every intermediate of stages 2-4
+that has a segment axis is (·, S): the (S, E) one-hot, the (3Q, S) pick at
+segment ends, the stage-3 (Q, S) lanes, the (Q, k + S) k-pass pool, and the
+accumulate kernel's (S, S + 128) placement window and ``S + 128`` lanes of
+accumulator slack.  Slots past the step's last row start hold no complete
+row (``complete`` is false there), so cutting S from ``seg_slot_cap(E)``
+(640 at E = 512) to the stream's bound (128 for rows of ~20 entries or
+more) removes only slots that are never filled: the per-row sums and picks
+are the same sums, and under the default ``linear`` loop the answers are
+bit-identical (``tests/test_segment_slots.py``).  A churn-stable index
+holds its bound monotone (it grows in steps of 128, and ``compact()``
+re-anchors it), so ingest retraces only when a step's row starts cross a
+bucket.
 """
 from __future__ import annotations
 
@@ -159,6 +181,32 @@ def _check_gather_mode(gather_mode: str, interpret: bool) -> None:
             "gather_mode='take' is an interpret-only reference gather (Mosaic "
             "lowers no 1-D gather); compiled kernels gather with 'onehot'"
         )
+
+
+def seg_slot_cap(e: int) -> int:
+    """Segment slots for one row per entry of an E-entry step, plus the
+    carried row, in whole lanes: the most any stream can need."""
+    return -(-(e + 1) // LANES) * LANES
+
+
+def segment_slots(max_starts: int, e: int) -> int:
+    """The segment space of a stream whose steps hold at most ``max_starts``
+    row starts: the starts plus segment 0, in whole lanes, at most
+    ``seg_slot_cap(e)``."""
+    return min(seg_slot_cap(e), -(-(int(max_starts) + 1) // LANES) * LANES)
+
+
+def _check_seg_slots(seg_slots, e: int) -> int:
+    """-> the step's segment space; ``None`` takes ``seg_slot_cap(e)``."""
+    cap = seg_slot_cap(e)
+    if seg_slots is None:
+        return cap
+    if seg_slots <= 0 or seg_slots % LANES or seg_slots > cap:
+        raise ValueError(
+            f"seg_slots must be a positive multiple of {LANES} at most "
+            f"{cap} for {e} entries per step, got {seg_slots}"
+        )
+    return seg_slots
 
 
 def _iota(shape, dim: int) -> jnp.ndarray:
@@ -352,17 +400,17 @@ def _gather_set_columns(x_chunks, id_cols, n_chunks, c: jnp.ndarray, q: int,
     return _sum3(jnp.where(jnp.any(hit, axis=0)[None, :], xv, 0.0))
 
 
-def _step_candidates(xg, v, f, carry_row, carry_sum, *, prefix_sums):
+def _step_candidates(xg, v, f, carry_row, carry_sum, *, prefix_sums, seg_slots):
     """Stages 1-3 of one grid step, for the gathered query values ``xg`` (Q, E).
 
     Returns the step's candidates in segment space — ``cand_v`` (Q, S),
-    ``cand_r`` (1, S) slot ids and ``complete`` (1, S) — plus the slot id of
-    segment 0, and advances the carry scratch.  Segment 0 continues the row
-    left open by the previous step; the step's last segment stays open.
+    ``cand_r`` (1, S) slot ids and ``complete`` (1, S), S = ``seg_slots`` —
+    plus the slot id of segment 0, and advances the carry scratch.  Segment
+    0 continues the row left open by the previous step; the step's last
+    segment stays open.  S must exceed the step's row starts.
     """
     e = v.shape[0] * v.shape[1]
     v, f = v.reshape(1, e), f.reshape(1, e)
-    s_pad = -(-(e + 1) // LANES) * LANES          # >= E+1 segment slots
 
     # ---- stage 1: multiply the gathered x ----
     prods = v * xg                                                    # (Q, E)
@@ -372,7 +420,7 @@ def _step_candidates(xg, v, f, carry_row, carry_sum, *, prefix_sums):
     seg = jnp.dot(
         f.astype(jnp.bfloat16), tri, preferred_element_type=jnp.float32,  # exact 0/1
     ).astype(jnp.int32)                                               # (1, E)
-    onehot = (_iota((s_pad, e), 0) == seg).astype(jnp.bfloat16)       # (S, E)
+    onehot = (_iota((seg_slots, e), 0) == seg).astype(jnp.bfloat16)       # (S, E)
     if prefix_sums:
         ps = _dot_split(_split3(prods), tri, NN)
         is_last = jnp.concatenate([f[:, 1:], jnp.ones((1, 1), f.dtype)], axis=1) == 1
@@ -384,7 +432,7 @@ def _step_candidates(xg, v, f, carry_row, carry_sum, *, prefix_sums):
 
     # ---- stage 3: cross-step carry (paper's new_row / last_packet_output) ----
     s_last = jnp.sum(f)                          # id of the step's open segment
-    sid = _iota((1, s_pad), 1)
+    sid = _iota((1, seg_slots), 1)
     row0 = carry_row[0]
     part = carry_sum[...]                                             # (Q, 1)
     cand_v = seg_sums + jnp.where(sid == 0, part, 0.0)
@@ -439,6 +487,7 @@ def _topk_kernel(
     stream_layout: str,
     block: int,
     col_words: int,
+    seg_slots: int,
 ):
     streams = refs[:n_streams]
     topv_ref, topr_ref, x_vmem, id_cols, acc_v, acc_r, carry_row, carry_sum = (
@@ -472,7 +521,7 @@ def _topk_kernel(
     c = c.reshape(1, -1)
     xg = _gather_set_columns(x_vmem, id_cols, n_chunks, c, q, gather_mode)
     cand_v, cand_r, complete, _ = _step_candidates(
-        xg, v, f, carry_row, carry_sum, prefix_sums=prefix_sums,
+        xg, v, f, carry_row, carry_sum, prefix_sums=prefix_sums, seg_slots=seg_slots,
     )
     cand_v = jnp.where(complete, cand_v, NEG_INF)
 
@@ -521,6 +570,7 @@ def _accum_kernel(
     stream_layout: str,
     block: int,
     col_words: int,
+    seg_slots: int,
 ):
     streams = refs[:n_streams]
     y_ref, y_acc, carry_row, carry_sum = refs[n_streams:]
@@ -536,7 +586,7 @@ def _accum_kernel(
     v, c, f = _decode_tile(streams, stream_layout, block, fmt, col_words)
     cand_v, _, complete, row0 = _step_candidates(
         _gather_x(x_ref[...], c.reshape(1, -1), gather_mode), v, f,
-        carry_row, carry_sum, prefix_sums=prefix_sums,
+        carry_row, carry_sum, prefix_sums=prefix_sums, seg_slots=seg_slots,
     )
     # ---- stage 4': place segment s at slot row0 + s (row0 >= -1) ----
     s_pad = cand_v.shape[1]
@@ -576,7 +626,7 @@ def _fused_geometry(width: int, block: int, fmt) -> int:
 
 
 def _stream_operands(vals, cols, flags, *, fmt_name, stream_layout, block_size,
-                     packets_per_step, gather_mode, interpret):
+                     packets_per_step, gather_mode, interpret, seg_slots):
     """Validate the knobs; -> (static kernel kwargs, 4-D streams, specs, grid)."""
     fmt = STREAM_FORMATS[fmt_name]
     if isinstance(fmt, TaggedFormatClass) and stream_layout != "fused":
@@ -610,7 +660,7 @@ def _stream_operands(vals, cols, flags, *, fmt_name, stream_layout, block_size,
     static = dict(
         n_streams=len(streams), num_steps=num_steps, fmt=fmt,
         gather_mode=gather_mode, stream_layout=stream_layout, block=block,
-        col_words=col_words,
+        col_words=col_words, seg_slots=_check_seg_slots(seg_slots, t * block),
     )
     return static, streams, specs, (n_cores, num_steps)
 
@@ -683,7 +733,7 @@ def _compact_query(x: jnp.ndarray, query_cols):
 
 _TOPK_STATICS = (
     "k", "n_rows", "packets_per_step", "fmt_name", "gather_mode",
-    "inner_loop", "stream_layout", "block_size", "interpret",
+    "inner_loop", "stream_layout", "block_size", "seg_slots", "interpret",
 )
 
 
@@ -706,6 +756,7 @@ def bscsr_topk_spmv_multiquery(
     inner_loop: str = "linear",
     stream_layout: str = "split",
     block_size: int = None,  # required for "fused" (W hides B); ignored otherwise
+    seg_slots: int = None,   # stage-2 segment space; None: seg_slot_cap(E)
 ):
     """Q queries share one stream pass; returns per-core (vals, rows), (C, Q, k).
 
@@ -719,11 +770,16 @@ def bscsr_topk_spmv_multiquery(
     finds them on the host); a column left out must be 0 in every query.
     It is data: every column count runs on one compiled signature, and the
     gather reads only the chunks that hold them.  ``None`` takes all columns.
+
+    ``seg_slots`` sizes stage 2's segment space; it must exceed every
+    step's row starts (``ops.PackedPartitions.seg_slots`` derives it from
+    the flags, ``ops.check_segment_slots`` checks it).  ``None`` takes one
+    slot per entry, which every stream fits.
     """
     static, streams, specs, grid = _stream_operands(
         vals, cols, flags, fmt_name=fmt_name, stream_layout=stream_layout,
         block_size=block_size, packets_per_step=packets_per_step,
-        gather_mode=gather_mode, interpret=interpret,
+        gather_mode=gather_mode, interpret=interpret, seg_slots=seg_slots,
     )
     q = x.shape[0]
     # constant over the grid: compacted and split once per call
@@ -779,13 +835,14 @@ def bscsr_topk_spmv(
     inner_loop: str = "linear",
     stream_layout: str = "split",
     block_size: int = None,
+    seg_slots: int = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One query: the multi-query kernel at Q=1; per-core (vals, rows), (C, k)."""
     v, r = bscsr_topk_spmv_multiquery(
         x[None, :], vals, cols, flags, query_cols, k=k, n_rows=n_rows, interpret=interpret,
         packets_per_step=packets_per_step, fmt_name=fmt_name,
         gather_mode=gather_mode, inner_loop=inner_loop,
-        stream_layout=stream_layout, block_size=block_size,
+        stream_layout=stream_layout, block_size=block_size, seg_slots=seg_slots,
     )
     return v[:, 0], r[:, 0]
 
@@ -794,7 +851,7 @@ def bscsr_topk_spmv(
     jax.jit,
     static_argnames=(
         "n_rows", "packets_per_step", "fmt_name", "gather_mode",
-        "inner_loop", "stream_layout", "block_size", "interpret",
+        "inner_loop", "stream_layout", "block_size", "seg_slots", "interpret",
     ),
 )
 def bscsr_spmv(
@@ -811,6 +868,7 @@ def bscsr_spmv(
     inner_loop: str = "linear",
     stream_layout: str = "split",
     block_size: int = None,
+    seg_slots: int = None,   # stage-2 segment space, as for the top-k kernel
 ) -> jnp.ndarray:
     """Accumulate-mode kernel pass: per-core dense slot sums, (C, n_rows) f32.
 
@@ -824,11 +882,9 @@ def bscsr_spmv(
     static, streams, specs, grid = _stream_operands(
         vals, cols, flags, fmt_name=fmt_name, stream_layout=stream_layout,
         block_size=block_size, packets_per_step=packets_per_step,
-        gather_mode=gather_mode, interpret=interpret,
+        gather_mode=gather_mode, interpret=interpret, seg_slots=seg_slots,
     )
-    e = packets_per_step * static["block"]
-    s_pad = -(-(e + 1) // LANES) * LANES
-    acc_len = -(-(n_rows + 1) // LANES) * LANES + s_pad + LANES
+    acc_len = -(-(n_rows + 1) // LANES) * LANES + static["seg_slots"] + LANES
     kernel = functools.partial(
         _accum_kernel, n_rows=n_rows, inner_loop=inner_loop, **static
     )

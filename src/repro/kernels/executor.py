@@ -77,8 +77,9 @@ from repro.kernels.bscsr_topk_spmv import (
 from repro.kernels.bscsr_topk_spmv import set_columns as find_set_columns
 from repro.utils.tracing import span
 
-# (snapshot uid, stream layout) -> DeviceSnapshot; entries evicted when the
-# host PackedPartitions is garbage collected.
+# (snapshot uid, stream layout, row-map key, device, packets per step) ->
+# DeviceSnapshot; entries evicted when the host PackedPartitions is garbage
+# collected.
 _DEVICE_CACHE: dict = {}
 
 
@@ -110,14 +111,18 @@ class DeviceSnapshot:
     ``args`` is the positional device-array tail every compiled query fn
     takes after the query itself; ``signature`` keys the jit cache (shapes,
     dtypes and static geometry — two snapshots with equal signatures can
-    share one compiled fn without retracing).
+    share one compiled fn without retracing).  ``seg_slots`` is the
+    kernels' segment space for steps of ``packets_per_step`` packets
+    (``PackedPartitions.seg_slots``), checked against the stream before
+    anything is pinned.
     """
 
     __slots__ = (
         "uid", "stream_layout", "streams", "row_starts", "rows_per_part",
         "slot_to_row", "tombstones", "row_map", "args", "signature",
-        "max_slots", "n_rows_logical", "n_rows_sentinel", "sentinel_index",
-        "block_size", "fmt_name", "groups_meta", "num_cores",
+        "max_slots", "seg_slots", "n_rows_logical", "n_rows_sentinel",
+        "sentinel_index", "block_size", "fmt_name", "groups_meta",
+        "num_cores",
     )
 
     def __init__(
@@ -126,9 +131,12 @@ class DeviceSnapshot:
         stream_layout: str,
         row_map=None,
         device=None,
+        packets_per_step: int = 2,
     ):
         self.uid = packed.uid
         self.stream_layout = stream_layout
+        self.seg_slots = packed.seg_slots(packets_per_step)
+        ops.check_segment_slots(packed, packets_per_step, self.seg_slots)
         if device is not None:
             # Pin on a specific device (the sharded plane places each shard
             # on its mesh column).  jax.default_device keeps jnp.array's
@@ -202,7 +210,7 @@ class DeviceSnapshot:
             self.slot_to_row is not None,
             self.tombstones is not None,
             self.row_map is not None,
-            self.max_slots, self.block_size,
+            self.max_slots, self.seg_slots, self.block_size,
             self.fmt_name,
             # Mixed precision: the per-partition format-code vector and the
             # width-class grouping are part of the compiled signature — a
@@ -232,6 +240,7 @@ def device_snapshot(
     row_map=None,
     row_map_key=None,
     device=None,
+    packets_per_step: int = 2,
 ) -> DeviceSnapshot:
     """The device-pinned form of ``packed``, uploading at most once per uid.
 
@@ -239,15 +248,18 @@ def device_snapshot(
     the snapshot (the key distinguishes pins of the same snapshot with and
     without a map — a given ``row_map_key`` must always name the same map
     contents for a given uid).  ``device`` commits the pin to a specific
-    device instead of the process default.
+    device instead of the process default.  ``packets_per_step`` is the
+    grid step the kernels will run, which fixes the segment space.
     """
     layout = stream_layout or packed.stream_layout
-    key = (packed.uid, layout, row_map_key, device)
+    key = (packed.uid, layout, row_map_key, device, packets_per_step)
     snap = _DEVICE_CACHE.get(key)
     if snap is None:
         with span("executor.pin") as s:
-            snap = DeviceSnapshot(packed, layout, row_map=row_map, device=device)
-            s.set_metadata(bytes=sum(int(a.nbytes) for a in snap.args))
+            snap = DeviceSnapshot(packed, layout, row_map=row_map, device=device,
+                                  packets_per_step=packets_per_step)
+            s.set_metadata(bytes=sum(int(a.nbytes) for a in snap.args),
+                           seg_slots=snap.seg_slots)
         _DEVICE_CACHE[key] = snap
         weakref.finalize(packed, _DEVICE_CACHE.pop, key, None)
     return snap
@@ -329,7 +341,7 @@ class QueryExecutor:
         self.interpret = ops.resolve_interpret(interpret)
         self.q_bucketing = q_bucketing
         self._fns: dict = {}
-        self._pinned: set = set()  # (uid, layout) keys this executor touched
+        self._pinned: set = set()  # device-cache keys this executor touched
         self._last_sig: dict = {}  # (path, q) -> signature it last compiled
         self.fn_builds = 0
         self.dispatches = 0
@@ -378,14 +390,16 @@ class QueryExecutor:
         snap = device_snapshot(
             packed, layout,
             row_map=row_map, row_map_key=row_map_key, device=device,
+            packets_per_step=self.packets_per_step,
         )
-        if (snap.uid, layout, row_map_key, device) not in self._pinned:
+        pin = (snap.uid, layout, row_map_key, device, self.packets_per_step)
+        if pin not in self._pinned:
             # A new pin means a snapshot refresh: drop dead pins now.  The
             # zero-retrace steady state never misses the fn cache, so
             # _evict_stale alone would let this set grow by one dead tuple
             # per upsert forever.
             self._pinned &= set(_DEVICE_CACHE.keys())
-            self._pinned.add((snap.uid, layout, row_map_key, device))
+            self._pinned.add(pin)
         key = (path, q, snap.signature)
         fn = self._fns.get(key)
         if fn is None:
@@ -537,6 +551,11 @@ class QueryExecutor:
         # prune dead pins so the count (and this set) track live pins only;
         # set() snapshots the keys against concurrent finalize-driven pops
         self._pinned &= set(_DEVICE_CACHE.keys())
+        seg_slots: dict = {}
+        for key in list(self._pinned):
+            snap = _DEVICE_CACHE.get(key)
+            if snap is not None:
+                seg_slots[snap.seg_slots] = seg_slots.get(snap.seg_slots, 0) + 1
         return {
             "compiled_fns": len(self._fns),
             "fn_builds": self.fn_builds,
@@ -549,6 +568,8 @@ class QueryExecutor:
             "interpret": self.interpret,                # False: Mosaic-compiled
             "gather_mode": self.gather_mode,
             "gather_widths": dict(sorted(self.gather_widths.items())),
+            # stage-2 segment space -> this executor's live pins holding it
+            "seg_slots": dict(sorted(seg_slots.items())),
             "paths": sorted({key[0] for key in self._fns}),  # compiled fn paths
         }
 
@@ -564,6 +585,7 @@ class QueryExecutor:
         fmt = FORMATS[snap.fmt_name]
         big_k, k = self.big_k, self.k
         max_slots = snap.max_slots
+        seg_slots = snap.seg_slots
 
         def split_args(arrs):
             streams = arrs[:n_streams]
@@ -607,7 +629,8 @@ class QueryExecutor:
                 packets_per_step=self.packets_per_step,
                 fmt_name=snap.fmt_name, gather_mode=self.gather_mode,
                 inner_loop=self.inner_loop, stream_layout=layout,
-                block_size=snap.block_size, interpret=self.interpret,
+                block_size=snap.block_size, seg_slots=seg_slots,
+                interpret=self.interpret,
             )
 
             if snap.groups_meta is not None:
@@ -694,7 +717,8 @@ class QueryExecutor:
                     packets_per_step=self.packets_per_step,
                     fmt_name=snap.fmt_name, gather_mode=self.gather_mode,
                     inner_loop=self.inner_loop, stream_layout=layout,
-                    block_size=snap.block_size, interpret=self.interpret,
+                    block_size=snap.block_size, seg_slots=seg_slots,
+                    interpret=self.interpret,
                 )
                 if snap.groups_meta is not None:
                     num_cores = snap.num_cores

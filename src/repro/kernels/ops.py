@@ -73,9 +73,11 @@ from repro.core.quantization import (
 )
 from repro.kernels import ref as ref_lib
 from repro.kernels.bscsr_topk_spmv import (
+    FLAG_WORD_BITS,
     bscsr_spmv,
     bscsr_topk_spmv,
     bscsr_topk_spmv_multiquery,
+    segment_slots,
 )
 
 NEG_INF = ref_lib.NEG_INF
@@ -105,6 +107,22 @@ def bucket_packets(n: int, multiple: int) -> int:
     answer.
     """
     return -(-pow2_bucket(n) // multiple) * multiple
+
+
+def max_step_row_starts(flag_words: np.ndarray, packets_per_step: int) -> int:
+    """Most row starts any grid step holds, over cores and steps.
+
+    ``flag_words`` is a (C, P, B/32) row-start bit stream; a step is each
+    run of ``packets_per_step`` packets from packet 0 (a ragged tail counts
+    as a step).  The kernel's segment space has to exceed this count.
+    """
+    c, p, _ = flag_words.shape
+    bits = np.bitwise_count(np.ascontiguousarray(flag_words).view(np.uint32))
+    per_packet = bits.sum(axis=2, dtype=np.int64)
+    t = packets_per_step
+    per_packet = np.pad(per_packet, ((0, 0), (0, -p % t)))
+    return int(per_packet.reshape(c, -1, t).sum(axis=2).max(initial=0))
+
 
 # Monotonic snapshot identities: the device-resident plane
 # (``kernels/executor.py``) pins each snapshot's arrays on device exactly
@@ -183,12 +201,17 @@ class PackedPartitions:
     # --- mixed-precision fields (None for a homogeneous snapshot) ---
     fmt_codes: Optional[np.ndarray] = None     # (C,) int32 per-partition codes
     groups: Optional[Tuple[StreamGroup, ...]] = None  # tagged fused streams
+    # --- churn-stable floor of the kernel's segment space (0: none) ---
+    seg_slots_floor: int = 0
     # init=False: always derived in __post_init__, never copied stale through
     # dataclasses.replace.
     uid: int = dataclasses.field(init=False, compare=False, repr=False,
                                  default=-1)
     has_tombstones: bool = dataclasses.field(init=False, compare=False,
                                              default=False)
+    # packets_per_step -> max_step_row_starts over every streamed array
+    _step_starts: dict = dataclasses.field(init=False, compare=False,
+                                           repr=False, default=None)
 
     def __post_init__(self):
         object.__setattr__(self, "uid", next(_SNAPSHOT_UIDS))
@@ -196,6 +219,31 @@ class PackedPartitions:
             self, "has_tombstones",
             self.tombstones is not None and bool(self.tombstones.any()),
         )
+        object.__setattr__(self, "_step_starts", {})
+
+    def max_step_row_starts(self, packets_per_step: int) -> int:
+        """Most row starts in one grid step of any stream the kernels read:
+        the split flags (the fused words carry the same bits) and, for a
+        mixed-precision snapshot, every width class's tagged words."""
+        cached = self._step_starts.get(packets_per_step)
+        if cached is None:
+            arrays = [self.flags]
+            if self.groups is not None:
+                wf = self.block_size // FLAG_WORD_BITS
+                arrays += [g.words[..., 1 : 1 + wf] for g in self.groups]
+            cached = max(max_step_row_starts(a, packets_per_step) for a in arrays)
+            self._step_starts[packets_per_step] = cached
+        return cached
+
+    def seg_slots(self, packets_per_step: int) -> int:
+        """The kernels' static segment space for this snapshot at
+        ``packets_per_step``: its own row-start bound, raised to a
+        churn-stable index's ``seg_slots_floor``, at most one slot per
+        entry (``bscsr_topk_spmv.segment_slots``)."""
+        e = packets_per_step * self.block_size
+        need = max(self.max_step_row_starts(packets_per_step),
+                   self.seg_slots_floor - 1)
+        return segment_slots(need, e)
 
     @property
     def num_cores(self) -> int:
@@ -793,6 +841,23 @@ def finalize_candidates_batched(
     return jax.vmap(fin, in_axes=(1, 1))(local_vals, local_rows)  # (Q, big_k)
 
 
+def check_segment_slots(packed: PackedPartitions, packets_per_step: int,
+                        seg_slots: int) -> None:
+    """Refuse a segment space some step of ``packed`` overflows.
+
+    Stage 2 drops every entry whose segment id is >= ``seg_slots`` without
+    a trace, so a bound that is too small gives wrong answers: it is
+    checked on the host before the stream is served, never clipped.
+    """
+    starts = packed.max_step_row_starts(packets_per_step)
+    if starts >= seg_slots:
+        raise ValueError(
+            f"a grid step of {packets_per_step} packets holds {starts} row "
+            f"starts, which {seg_slots} segment slots cannot hold (segment 0 "
+            f"is the carried row)"
+        )
+
+
 def _finalize_kwargs(packed: PackedPartitions) -> dict:
     """Device-array finalize inputs for a packed snapshot (shared by paths)."""
     kw = dict(
@@ -880,7 +945,8 @@ def _grouped_local_topk(
             k=k, n_rows=packed.max_slots, packets_per_step=packets_per_step,
             fmt_name=g.class_name, gather_mode=gather_mode,
             inner_loop=inner_loop, stream_layout="fused",
-            block_size=packed.block_size, interpret=interpret,
+            block_size=packed.block_size,
+            seg_slots=packed.seg_slots(packets_per_step), interpret=interpret,
         )
         kernel = bscsr_topk_spmv_multiquery if batched else bscsr_topk_spmv
         gv, gr = kernel(x, jnp.asarray(g.words), **common)
@@ -929,6 +995,7 @@ def topk_spmv_blocked(
         inner_loop=inner_loop,
         stream_layout=layout,
         block_size=packed.block_size,
+        seg_slots=packed.seg_slots(packets_per_step),
         interpret=interpret,
     )
     return finalize_candidates(lv, lr, big_k=big_k, **_finalize_kwargs(packed))
@@ -976,6 +1043,7 @@ def topk_spmv_batched(
         inner_loop=inner_loop,
         stream_layout=layout,
         block_size=packed.block_size,
+        seg_slots=packed.seg_slots(packets_per_step),
         interpret=interpret,
     )
     return finalize_candidates_batched(
@@ -1068,7 +1136,8 @@ def _grouped_slot_sums(
             n_rows=packed.max_slots, packets_per_step=packets_per_step,
             fmt_name=g.class_name, gather_mode=gather_mode,
             inner_loop=inner_loop, stream_layout="fused",
-            block_size=packed.block_size, interpret=interpret,
+            block_size=packed.block_size,
+            seg_slots=packed.seg_slots(packets_per_step), interpret=interpret,
         )
         cores = jnp.asarray(np.asarray(g.cores, np.int32))
         sums = sums.at[cores].set(gs)
@@ -1119,6 +1188,7 @@ def bscsr_spmv_blocked(
             inner_loop=inner_loop,
             stream_layout=layout,
             block_size=packed.block_size,
+            seg_slots=packed.seg_slots(packets_per_step),
             interpret=interpret,
         )
     ax = scatter_slot_sums(sums, n_out=n_out, **_scatter_kwargs(packed))

@@ -135,9 +135,9 @@ class TestDevicePinning:
         packed = ops.pack_partitions(csr, 2, 32, "F32", stream_layout="fused")
         ex = executor_lib.QueryExecutor(big_k=BIG_K, k=8)
         ex.query(jnp.asarray(x), packed)
-        # cache key: (uid, layout, row_map_key, device) — no row map and no
-        # explicit device pin on this plain dispatch
-        key = (packed.uid, "fused", None, None)
+        # cache key: (uid, layout, row_map_key, device, packets_per_step) —
+        # no row map and no explicit device pin on this plain dispatch
+        key = (packed.uid, "fused", None, None, ex.packets_per_step)
         assert key in executor_lib._DEVICE_CACHE
         del packed
         gc.collect()
@@ -449,6 +449,66 @@ class TestChurnStable:
             ex.query(xd, b.packed)
         assert ex.fn_builds == 2
         assert ex.retraces == 0
+
+    @staticmethod
+    def _seg_index():
+        """A churn-stable index at E = 256 entries per step (cap 384 slots),
+        warmed past the first mutation's packet-cap jump; rows of ~8
+        entries bound its segment space at 128.  Its slot, tombstone and
+        packet buckets have room for the churn below, so only the segment
+        space can move a signature."""
+        csr, x = make_problem(n_rows=600, seed=31)
+        cfg = TopKSpMVConfig(big_k=BIG_K, k=8, num_partitions=2, block_size=128)
+        index = MutableTopKSpMVIndex(csr, cfg)
+        ex = executor_lib.QueryExecutor(big_k=BIG_K, k=8)
+        xs = jnp.asarray(np.stack([x, -x]))
+        index.add_rows([(np.arange(5, dtype=np.int32),
+                         np.ones(5, np.float32))])
+        ex.query_batched(xs, index.packed)
+        assert ex.cache_info()["seg_slots"] == {128: 1}
+        return index, ex, xs
+
+    def test_seg_slots_zero_retrace_inside_bucket(self):
+        index, ex, xs = self._seg_index()
+        retraces, builds = ex.retraces, ex.fn_builds
+        rng = np.random.default_rng(32)
+        for _ in range(3):
+            index.add_rows([(np.sort(rng.choice(64, 6, replace=False)).astype(np.int32),
+                             rng.standard_normal(6).astype(np.float32))
+                            for _ in range(4)])
+            gc.collect()
+            ex.query_batched(xs, index.packed)
+            assert ex.cache_info()["seg_slots"] == {128: 1}
+        assert (ex.retraces, ex.fn_builds) == (retraces, builds)
+
+    def test_seg_slots_bucket_crossing_retraces_once(self):
+        """Many one-entry rows put 128+ row starts in one step: the bound
+        moves to the next bucket (256), one counted retrace, and the
+        answers are still the reference's."""
+        index, ex, xs = self._seg_index()
+        retraces = ex.retraces
+        buckets = {k: v for k, v in index.packed.signature_info().items()
+                   if k.endswith("_bucket")}
+        rng = np.random.default_rng(33)
+        index.add_rows([(np.array([j % 64], np.int32),
+                         rng.standard_normal(1).astype(np.float32))
+                        for j in range(260)])
+        assert buckets == {k: v for k, v in index.packed.signature_info().items()
+                           if k.endswith("_bucket")}
+        gc.collect()   # the replaced snapshot must be dead to count as churn
+        got = ex.query_batched(xs, index.packed)
+        assert ex.cache_info()["seg_slots"] == {256: 1}
+        assert ex.retraces == retraces + 1
+        want = ex.query_batched(xs, index.packed, path="reference")
+        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                                   rtol=1e-5, atol=1e-5)
+        # the bound holds: a small upsert after the crossing keeps 256
+        index.add_rows([(np.arange(5, dtype=np.int32), np.ones(5, np.float32))])
+        gc.collect()
+        ex.query_batched(xs, index.packed)
+        assert ex.cache_info()["seg_slots"] == {256: 1}
+        assert ex.retraces == retraces + 1
 
     def test_pow2_buckets(self):
         assert [ops.pow2_bucket(n) for n in (1, 2, 3, 4, 5, 130)] == [

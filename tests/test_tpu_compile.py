@@ -174,3 +174,28 @@ def test_take_gather_refuses_to_compile(one_chip):
             k=K, n_rows=SLOTS, fmt_name="BF16", stream_layout="fused",
             block_size=BLOCK, gather_mode="take", interpret=False,
         )
+
+
+@pytest.mark.parametrize("m", [M, 30522])
+def test_multiquery_kernel_compiles_with_bounded_segments(one_chip, m):
+    # The segment space the benchmark collections bound at (128 of 640).
+    query_cols = (
+        _sds((chunk_capacity(m) * CHUNK,), jnp.int32, one_chip)
+        if chunk_capacity(m) > 1 else None
+    )
+    _compiles(
+        bscsr_topk_spmv_multiquery, _sds((64, m), jnp.float32, one_chip),
+        _fused_stream("BF16", one_chip), None, None, query_cols, k=K,
+        n_rows=SLOTS, fmt_name="BF16", stream_layout="fused", block_size=BLOCK,
+        seg_slots=128,
+    )
+
+
+@pytest.mark.parametrize("inner_loop", ["linear", "legacy"])
+def test_accumulate_kernel_compiles_with_bounded_segments(one_chip, inner_loop):
+    _compiles(
+        bscsr_spmv, _sds((M,), jnp.float32, one_chip),
+        _fused_stream("BF16", one_chip), n_rows=SLOTS, fmt_name="BF16",
+        inner_loop=inner_loop, stream_layout="fused", block_size=BLOCK,
+        seg_slots=128,
+    )
